@@ -18,6 +18,7 @@ from __future__ import annotations
 import argparse
 
 from benchmarks.common import FAST_STEPS, fmt_table, run_strategy, save_json
+from repro.launch.compile_cache import configure_compile_cache
 
 FREQS = [10, 50, 100]
 FREQ_STRATEGIES = ["checkpoint", "tiered_ckpt"]   # sweep ckpt_every
@@ -90,4 +91,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    configure_compile_cache()
     main()

@@ -10,6 +10,7 @@ checkpointing trails because each failure rolls the model back.
 from __future__ import annotations
 
 from benchmarks.common import FAST_STEPS, fmt_table, run_strategy, save_json
+from repro.launch.compile_cache import configure_compile_cache
 
 STRATEGIES = ["checkpoint", "redundant", "checkfree", "checkfree_plus"]
 
@@ -40,4 +41,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    configure_compile_cache()
     main()

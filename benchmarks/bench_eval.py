@@ -18,6 +18,7 @@ import numpy as np
 from benchmarks.common import (BENCH_BATCH, BENCH_MODEL, BENCH_SEQ,
                                FAST_STEPS, data_source, fmt_table,
                                load_params, run_strategy, save_json)
+from repro.launch.compile_cache import configure_compile_cache
 from repro.data.pipeline import SyntheticLM, batch_for
 from repro.models.model import build_model
 
@@ -83,4 +84,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    configure_compile_cache()
     main()

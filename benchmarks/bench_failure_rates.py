@@ -11,6 +11,7 @@ figure doubles as a live parity check of the simulator's legacy adapter.
 from __future__ import annotations
 
 from benchmarks.common import FAST_STEPS, fmt_table, run_strategy, save_json
+from repro.launch.compile_cache import configure_compile_cache
 
 RATES = [0.0, 0.05, 0.10, 0.16]
 
@@ -40,4 +41,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    configure_compile_cache()
     main()

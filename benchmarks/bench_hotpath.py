@@ -7,8 +7,9 @@ benchmark that seeds the repo's perf trajectory.  For each model family it
 runs the same failure-free training loop at ``fuse_window=1`` (the eager
 per-step loop: one dispatch + one blocking metrics drain per step) and at
 fused window sizes (one dispatch + one drain per K steps), asserts the
-fused loss trace is *bit-identical* to the eager one (same backend, same
-scan executable — see docs/perf.md), and reports steps/s + speedups.
+fused loss trace is *bit-identical* to the eager one (same backend, one
+loop body for every window size — see docs/perf.md), and reports steps/s
++ speedups.
 
 Results land in ``benchmarks/results/BENCH_hotpath.json``.  ``--smoke``
 runs the paper_llama smoke config only and fails hard unless the fused
@@ -25,6 +26,7 @@ from typing import Any, Dict, List, Optional
 import numpy as np
 
 from benchmarks.common import fmt_table, save_json
+from repro.launch.compile_cache import configure_compile_cache
 from repro.config import (ModelConfig, OptimizerConfig, RecoveryConfig,
                           TrainConfig)
 from repro.configs import get_config, reduced
@@ -150,23 +152,25 @@ def main() -> None:
                          "loss-trace match (CI gate)")
     ap.add_argument("--backend", default="host", choices=["host", "spmd"],
                     help="'spmd' times the pipeline-parallel shard_map "
-                         "backend (needs one host device per stage: launch "
-                         "with XLA_FLAGS=--xla_force_host_platform_device_"
-                         "count=2 or let this script force it); results "
-                         "land in BENCH_hotpath_spmd.json")
+                         "backend (needs one device per stage; under "
+                         "JAX_PLATFORMS=cpu this script forces 2 host "
+                         "devices); results land in "
+                         "BENCH_hotpath_spmd.json")
     ap.add_argument("--steps", type=int, default=0)
     args = ap.parse_args()
 
     if args.backend == "spmd":
-        # one device per stage (the bench families use 2); must happen
-        # before jax's first backend query
+        # one device per stage (the bench families use 2): virtual host
+        # devices under JAX_PLATFORMS=cpu (before jax's first backend
+        # query), the chips otherwise
         from repro.launch.mesh import force_host_devices
         force_host_devices(2)
         import jax
         if len(jax.devices()) < 2:
             raise SystemExit(
-                "spmd bench needs >= 2 host devices; relaunch with "
-                "XLA_FLAGS=--xla_force_host_platform_device_count=2")
+                f"spmd bench needs >= 2 devices, found "
+                f"{len(jax.devices())} {jax.devices()[0].platform} "
+                "device(s); on the CPU run with JAX_PLATFORMS=cpu")
 
     if args.smoke:
         steps = args.steps or 128
@@ -197,4 +201,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    configure_compile_cache()
     main()

@@ -18,6 +18,7 @@ from benchmarks.common import (BENCH_BATCH, BENCH_MODEL, BENCH_SEQ,
                                BENCH_STAGES, FAST_STEPS, data_source,
                                fmt_table, load_params, run_strategy,
                                save_json)
+from repro.launch.compile_cache import configure_compile_cache
 from repro.config import OptimizerConfig
 from repro.core.recovery import recover_stage, recovery_error
 from repro.core.stages import StagePartition
@@ -120,4 +121,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    configure_compile_cache()
     main()

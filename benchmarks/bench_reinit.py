@@ -9,6 +9,7 @@ from __future__ import annotations
 
 from benchmarks.common import (FAST_STEPS, fmt_table, iters_to_target,
                                run_strategy, save_json)
+from repro.launch.compile_cache import configure_compile_cache
 
 STRATEGIES = ["random", "copy", "uniform", "checkfree"]
 
@@ -44,4 +45,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    configure_compile_cache()
     main()

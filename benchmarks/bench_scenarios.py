@@ -20,6 +20,7 @@ import math
 from typing import List, Optional
 
 from benchmarks.common import FAST_STEPS, fmt_table, run_strategy, save_json
+from repro.launch.compile_cache import configure_compile_cache
 
 SCENARIOS = ["paper_10pct", "spot_diurnal", "flash_crowd", "wearout",
              "spot_shrink", "trace:spot_demo.jsonl"]
@@ -114,4 +115,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    configure_compile_cache()
     main()

@@ -8,6 +8,7 @@ recoverability.
 from __future__ import annotations
 
 from benchmarks.common import FAST_STEPS, fmt_table, run_strategy, save_json
+from repro.launch.compile_cache import configure_compile_cache
 
 
 def run(steps: int = FAST_STEPS, verbose: bool = False):
@@ -35,4 +36,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    configure_compile_cache()
     main()

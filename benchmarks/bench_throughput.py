@@ -10,6 +10,7 @@ from __future__ import annotations
 
 from benchmarks.common import (FAST_STEPS, fmt_table, run_strategy,
                                save_json, wall_to_target)
+from repro.launch.compile_cache import configure_compile_cache
 
 STRATEGIES = ["checkpoint", "redundant", "checkfree", "checkfree_plus"]
 RATES = [0.05, 0.10, 0.16]
@@ -58,4 +59,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    configure_compile_cache()
     main()

@@ -10,8 +10,8 @@ failure patterns between tests are the same", §5.1).
 Wall-clock is the paper-calibrated analytic model (core/walltime.py): CPU
 convergence (iterations) x per-iteration cost per strategy (Table 2's
 91.3 s / 151.0 s) + per-failure costs.  Runs are cached in
-``benchmarks/results/cache`` keyed by their full parameterization, so the
-figure benches can share runs.
+``benchmarks/results/cache`` keyed by their full parameterization and the
+device they ran on, so the figure benches can share runs.
 """
 from __future__ import annotations
 
@@ -74,6 +74,8 @@ def env_fingerprint() -> Dict[str, Any]:
     import platform
 
     import jax
+
+    from repro.kernels.ops import interpret_default
     devs = jax.devices()
     return dict(
         jax=jax.__version__,
@@ -82,7 +84,7 @@ def env_fingerprint() -> Dict[str, Any]:
         backend=jax.default_backend(),
         device_kind=devs[0].device_kind if devs else "none",
         device_count=len(devs),
-        pallas_interpret=os.environ.get("REPRO_PALLAS_INTERPRET", ""),
+        pallas_interpret=interpret_default(),
     )
 
 
@@ -106,6 +108,22 @@ def _cache_key(kw: Dict[str, Any]) -> str:
     return hashlib.sha1(blob).hexdigest()[:16]
 
 
+def run_key(env: Dict[str, Any], **params: Any) -> Dict[str, Any]:
+    """What a cached run is keyed on: its parameters and the device it
+    runs on (``env`` is :func:`env_fingerprint`), so a CPU result is never
+    served for a chip run of the same parameters, or the reverse."""
+    kw = dict(params, model=BENCH_MODEL.name, stages=BENCH_STAGES, v=8,
+              platform=env["backend"], device_kind=env["device_kind"],
+              device_count=env["device_count"])
+    scenario = params.get("scenario")
+    if scenario is not None and scenario.startswith("trace:"):
+        # key the cache on the trace *contents*: editing the file must miss
+        from repro.sim import resolve_trace_path
+        with open(resolve_trace_path(scenario[len("trace:"):]), "rb") as f:
+            kw["trace_sha"] = hashlib.sha1(f.read()).hexdigest()[:12]
+    return kw
+
+
 def run_strategy(*, strategy: str, rate: Optional[float] = None,
                  scenario: Optional[str] = None,
                  steps: int = FAST_STEPS, seed: int = 0,
@@ -126,14 +144,10 @@ def run_strategy(*, strategy: str, rate: Optional[float] = None,
     """
     if scenario is None and rate is None:
         rate = 0.10  # the legacy schedule's long-standing default
-    kw = dict(strategy=strategy, rate=rate, scenario=scenario, steps=steps,
-              seed=seed, ckpt_every=ckpt_every, failure_seed=failure_seed,
-              lr=lr, model=BENCH_MODEL.name, stages=BENCH_STAGES, v=8)
-    if scenario is not None and scenario.startswith("trace:"):
-        # key the cache on the trace *contents*: editing the file must miss
-        from repro.sim import resolve_trace_path
-        with open(resolve_trace_path(scenario[len("trace:"):]), "rb") as f:
-            kw["trace_sha"] = hashlib.sha1(f.read()).hexdigest()[:12]
+    env = env_fingerprint()
+    kw = run_key(env, strategy=strategy, rate=rate, scenario=scenario,
+                 steps=steps, seed=seed, ckpt_every=ckpt_every,
+                 failure_seed=failure_seed, lr=lr)
     os.makedirs(CACHE_DIR, exist_ok=True)
     path = os.path.join(CACHE_DIR, _cache_key(kw) + ".json")
     if use_cache and os.path.exists(path):
@@ -189,7 +203,7 @@ def run_strategy(*, strategy: str, rate: Optional[float] = None,
     rec = dict(
         params_path=path.replace(".json", "_params.npz"),
         config=kw,
-        env=env_fingerprint(),
+        env=env,
         entropy_floor=data_source().entropy_floor,
         steps=hist.steps, wall_time=hist.wall_time, loss=hist.loss,
         eval_loss=hist.eval_loss, failures=hist.failures,

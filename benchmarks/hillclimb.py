@@ -22,6 +22,7 @@ import json
 import os
 
 from benchmarks.common import RESULTS_DIR
+from repro.launch.compile_cache import configure_compile_cache
 
 LEVER_KEYS = ("REPRO_ACT_SHARD", "REPRO_PARAM_SHARD", "REPRO_MOE_GROUP",
               "REPRO_REMAT")
@@ -82,4 +83,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    configure_compile_cache()
     main()

@@ -14,6 +14,8 @@ import argparse
 import time
 import traceback
 
+from repro.launch.compile_cache import configure_compile_cache
+
 BENCHES = {
     "kernels": ("kernel microbenches vs oracle", "benchmarks.bench_kernels"),
     "fig2": ("reinit strategies", "benchmarks.bench_reinit"),
@@ -57,4 +59,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    configure_compile_cache()
     main()

@@ -166,7 +166,7 @@ class ModuleIndex:
         self.functions: Dict[str, ast.AST] = {}
         self.parent: Dict[ast.AST, ast.AST] = {}
         # name -> Call it was last assigned from (partial-bound kernels) and
-        # name -> Name/Attribute alias (`_mk = make_compat_mesh`)
+        # name -> Name/Attribute alias (`mk = make_mesh`)
         self.assigned_calls: Dict[str, ast.Call] = {}
         self.name_aliases: Dict[str, str] = {}
         for node in ast.walk(tree):
@@ -315,7 +315,7 @@ class ProjectContext:
             fn = index.canonical_callee(node.func)
             leaf = fn.split(".")[-1].lower() if fn is not None else ""
             # Mesh(devices, axis_names), jax.make_mesh(shape, names), and
-            # repo factories (make_compat_mesh/make_pipeline_mesh/...) all
+            # repo factories (make_mesh/make_pipeline_mesh/...) all
             # put the axis-name tuple in the second positional slot
             if fn in MESH_CTORS or "mesh" in leaf:
                 cands: List[ast.AST] = node.args[1:2]
@@ -323,7 +323,7 @@ class ProjectContext:
                           if kw.arg in ("axis_names", "axes")]
                 for c in cands:
                     # axis tuples are often staged through a local var:
-                    # `axes = ("pod", "data") if multi else ...; _mk(s, axes)`
+                    # `axes = ("pod", "data") if multi else ...; make_mesh(s, axes)`
                     if isinstance(c, ast.Name):
                         for n2 in ast.walk(index.tree):
                             if isinstance(n2, ast.Assign) and any(

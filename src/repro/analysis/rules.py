@@ -3,7 +3,7 @@
 Each rule guards an invariant a prior PR introduced (see
 ``docs/static_analysis.md`` for the rule table and rationale):
 
-* ``host-sync-in-jit`` — the fused ``lax.scan`` window (PR 4) is only a win
+* ``host-sync-in-jit`` — the fused loop window is only a win
   if nothing inside the traced region forces a host round-trip.
 * ``collective-axis-consistency`` — CheckFree+ recovery *is* ``psum`` /
   ``ppermute`` collectives (PR 5); a typo'd axis name silently corrupts the
@@ -17,8 +17,8 @@ Each rule guards an invariant a prior PR introduced (see
   step (PR 4); touching them after dispatch reads freed buffers on donating
   backends.
 * ``pallas-contract`` — BlockSpec rank / index_map arity / grid must agree,
-  and the interpret flag must be read at call time (PR 4's env-flip
-  contract), not baked in at import.
+  and the interpret flag must be chosen at call time (it follows the
+  platform the call runs on), not baked in at import.
 """
 from __future__ import annotations
 
@@ -725,7 +725,7 @@ class PallasContract(Rule):
             yield self.finding(
                 index, interp,
                 "pallas_call at module scope freezes `interpret` at import "
-                "time; read the flag at call time (kernels/ops.py pattern)")
+                "time; choose it at call time (kernels/ops.py pattern)")
 
     def _check_import_time_interpret(self, index: ModuleIndex,
                                      ) -> Iterable[Finding]:
@@ -741,8 +741,8 @@ class PallasContract(Rule):
                         yield self.finding(
                             index, node,
                             "interpret flag read at import time; call "
-                            "`interpret_default()` at dispatch so flipping "
-                            "REPRO_PALLAS_INTERPRET mid-process works")
+                            "`interpret_default()` at dispatch so the "
+                            "choice follows the platform the call runs on")
                     elif canon.startswith("os.environ") or canon in (
                             "os.getenv",):
                         if any(isinstance(a, ast.Constant)
@@ -751,8 +751,9 @@ class PallasContract(Rule):
                                for a in node.args):
                             yield self.finding(
                                 index, node,
-                                "REPRO_PALLAS_INTERPRET read at import "
-                                "time; read it at call time instead")
+                                "an interpret switch read from the "
+                                "environment at import time; choose "
+                                "interpret mode at call time instead")
                 elif isinstance(node, ast.Subscript):
                     base = res.canonical(node.value) or ""
                     if base == "os.environ" and isinstance(
@@ -761,8 +762,9 @@ class PallasContract(Rule):
                             INTERPRET_ENV in node.slice.value:
                         yield self.finding(
                             index, node,
-                            "REPRO_PALLAS_INTERPRET read at import time; "
-                            "read it at call time instead")
+                            "an interpret switch read from the "
+                            "environment at import time; choose "
+                            "interpret mode at call time instead")
 
 
 # ---------------------------------------------------------------------------

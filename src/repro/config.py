@@ -258,7 +258,7 @@ class TrainConfig:
     eval_every: int = 50
     eval_batches: int = 4
     fuse_window: int = 8      # max iterations fused into one on-device
-                              # lax.scan window (1 = eager per-step loop);
+                              # loop window (1 = eager per-step loop);
                               # the trainer buckets actual windows to powers
                               # of two and breaks at failures, eval points,
                               # and the strategy's after_step_horizon

@@ -14,18 +14,19 @@ swapped stage order (a static layer-index gather — see core/swap.py).
 **Fused hot path.**  The failure schedule is deterministic and queryable
 ahead of time (``schedule.at(step)``), so between failure events the
 trainer knows it will run K uninterrupted steps.  It fuses them into a
-single jitted ``lax.scan`` over a stacked batch window: one dispatch, zero
+single jitted loop over a stacked batch window: one dispatch, zero
 per-step host round-trips.  Per-step metrics (loss, per-stage grad
 square-norms, lr) accumulate on device in the scan's output ring and are
 drained with one ``device_get`` at window boundaries — failure events,
-eval points, strategy ``after_step_horizon`` limits, and run end.  Window
-size 1 runs the *same* scan executable with a length-1 leading axis, so
-eager and fused traces are bit-identical by construction.  Params and
-optimizer state are donated to the step (``donate_argnums``), so on
-backends with real donation Adam's moments update in place instead of
-being copied every iteration (CPU ignores donation; the jit warning is
-silenced below).  The next window's batches are stacked on a background
-thread (:class:`~repro.data.pipeline.WindowPrefetcher`) while the current
+eval points, strategy ``after_step_horizon`` limits, and run end.  The
+window's trip count is a runtime operand of the loop (:func:`window_loop`),
+so XLA compiles one loop body for every window size and eager (window 1)
+and fused traces are bit-identical on one backend.  Params and optimizer
+state are donated to the step (``donate_argnums``): Adam's moments update
+in place instead of being copied every iteration, and a donated buffer is
+deleted once the step is dispatched.  The next window's batches are
+stacked on a background thread
+(:class:`~repro.data.pipeline.WindowPrefetcher`) while the current
 window runs, and the replay cache is bounded by the strategy's
 ``replay_horizon()``.  See ``docs/perf.md``.
 
@@ -96,21 +97,59 @@ def _make_loss_fn(model: Model, part: StagePartition, use_swap: bool,
     return loss_fn
 
 
+def window_loop(step: Callable, carry: Any, stacked: Any, n: jnp.ndarray,
+                ) -> Tuple[Any, Any]:
+    """``lax.scan(step, carry, stacked)`` with the trip count ``n`` passed
+    in as a runtime operand instead of the static leading axis.
+
+    XLA specializes a loop whose trip count it can see, and a window of 1
+    then compiles to a body whose reductions round differently from the
+    body of a window of K (last-bit loss differences).  With ``n`` opaque
+    the loop body is one program for every window size, which is what
+    makes eager and fused traces bit-identical.  ``n`` must equal the
+    leading axis of ``stacked``; the per-step outputs are written into a
+    ring with that leading axis."""
+    length = jax.tree.leaves(stacked)[0].shape[0]
+    first = jax.tree.map(lambda x: x[0], stacked)
+    out_shapes = jax.eval_shape(step, carry, first)[1]
+    ring0 = jax.tree.map(lambda s: jnp.zeros((length,) + s.shape, s.dtype),
+                         out_shapes)
+
+    def body(i, state):
+        carry, ring = state
+        batch = jax.tree.map(
+            lambda x: jax.lax.dynamic_index_in_dim(x, i, keepdims=False),
+            stacked)
+        carry, out = step(carry, batch)
+        ring = jax.tree.map(
+            lambda r, o: jax.lax.dynamic_update_index_in_dim(r, o, i, 0),
+            ring, out)
+        return carry, ring
+
+    return jax.lax.fori_loop(0, n, body, (carry, ring0))
+
+
 def _jit_donated(fn):
-    """jit with params/opt_state (argnums 0, 1) donated: on backends with
-    donation support Adam's moments update in place instead of being copied
-    every step; elsewhere (CPU) donation is a no-op that warns once per
-    compile.  That warning is suppressed *scoped to this dispatch only* —
-    the process-global filter is left alone so callers' own donation
-    misconfigurations still surface."""
+    """jit ``fn(params, opt_state, stacked, lr_scale, n)`` with
+    params/opt_state (argnums 0, 1) donated, and dispatch it as
+    ``fn(params, opt_state, stacked, lr_scale)``: the wrapper passes the
+    window length ``n`` as a runtime int32 (see :func:`window_loop`).
+    Donated buffers are consumed by the call; the "not usable" warning a
+    backend gives for a donated buffer it cannot alias is suppressed
+    *scoped to this dispatch only* — the process-global filter is left
+    alone so callers' own donation misconfigurations still surface."""
     jitted = jax.jit(fn, donate_argnums=(0, 1))
 
     @functools.wraps(jitted)
     def dispatch(*args):
+        # args = (params, opt_state, stacked, lr_scale)
+        n = np.int32(jax.tree.leaves(args[2])[0].shape[0])
         with warnings.catch_warnings():
             warnings.filterwarnings(
                 "ignore", message="Some donated buffers were not usable")
-            return jitted(*args)
+            # n (the trip count) is not donated: the lint counts positions
+            # from the starred args
+            return jitted(*args, n)  # repro: allow[donation-after-dispatch]
 
     # the retrace sentinel (repro.analysis.runtime) counts compiled
     # variants through the wrapper
@@ -145,8 +184,8 @@ def make_train_step(model: Model, opt_cfg: OptimizerConfig,
 def make_fused_train_step(model: Model, opt_cfg: OptimizerConfig,
                           part: StagePartition, *, use_swap: bool = False,
                           lr_decay: float = 1.0) -> Callable:
-    """Build the fused K-step train step: a jitted ``lax.scan`` over a
-    stacked batch window.
+    """Build the fused K-step train step: a jitted loop over a stacked
+    batch window.
 
     ``fused(params, opt_state, stacked, lr_scale)`` runs one scan step per
     leading-axis slice of ``stacked`` and returns
@@ -159,14 +198,15 @@ def make_fused_train_step(model: Model, opt_cfg: OptimizerConfig,
     ``opt_state`` are donated: on backends with donation support Adam's
     moments update in place across the whole window.
 
-    The window size is purely the leading axis of ``stacked`` — K=1 runs
-    the identical scan body, which is what makes eager (window 1) and fused
-    (window K) loss traces bit-identical on the same backend.
+    The window size is purely the leading axis of ``stacked``; the loop's
+    trip count reaches XLA as a runtime operand (:func:`window_loop`), so
+    K=1 runs the identical loop body, which is what makes eager (window 1)
+    and fused (window K) loss traces bit-identical on the same backend.
     """
     loss_fn = _make_loss_fn(model, part, use_swap)
 
     @_jit_donated
-    def fused_step(params, opt_state, stacked, lr_scale):
+    def fused_step(params, opt_state, stacked, lr_scale, n):
         def body(carry, batch):
             params, opt_state, ls = carry
             (loss, metrics), grads = jax.value_and_grad(
@@ -181,7 +221,7 @@ def make_fused_train_step(model: Model, opt_cfg: OptimizerConfig,
             return (params, opt_state, ls_next), ring
 
         carry0 = (params, opt_state, jnp.asarray(lr_scale, jnp.float32))
-        (params, opt_state, ls), outs = jax.lax.scan(body, carry0, stacked)
+        (params, opt_state, ls), outs = window_loop(body, carry0, stacked, n)
         return params, opt_state, ls, outs
 
     return fused_step
@@ -256,9 +296,12 @@ class Trainer:
         if backend == "spmd":
             from repro.launch.mesh import make_host_pipeline_mesh
             from repro.pipeline.spmd import (make_in_mesh_recover,
-                                             make_spmd_fused_train_step)
+                                             make_spmd_fused_train_step,
+                                             pipeline_state_shardings)
             self.mesh = (mesh if mesh is not None
                          else make_host_pipeline_mesh(self.rcfg.num_stages))
+            self._state_shardings = pipeline_state_shardings(
+                self.mesh, jax.eval_shape(fresh_init))
             self.fused_step = make_spmd_fused_train_step(
                 model, tcfg.optimizer, self.part, self.mesh,
                 tcfg.num_microbatches,
@@ -269,6 +312,7 @@ class Trainer:
                     make_in_mesh_recover(self.mesh, self.part))
         elif backend == "host":
             self.mesh = None
+            self._state_shardings = None
             self.fused_step = make_fused_train_step(
                 model, tcfg.optimizer, self.part,
                 use_swap=self.strategy.uses_swap_schedule,
@@ -299,6 +343,18 @@ class Trainer:
                 "degrade to in-place recovery on a spare")
         # (wall_step, direction, from_k, to_k, moved_layers, cost_s)
         self.repartition_log: List[Tuple[int, str, int, int, int, float]] = []
+
+    def _placed(self, state: TrainState) -> TrainState:
+        """On the spmd backend, put params and Adam state where the fused
+        step keeps them (the tower stage-sharded, the rest replicated).
+        A fresh init or a restored checkpoint otherwise arrives on one
+        device, and the fused step would compile a second executable for
+        that placement; arrays already in place are not copied."""
+        if self._state_shardings is None:
+            return state
+        params, opt_state = jax.device_put((state.params, state.opt_state),
+                                           self._state_shardings)
+        return dataclasses.replace(state, params=params, opt_state=opt_state)
 
     # ---- window sizing -------------------------------------------------
     def _window_size(self, wall_step: int, effective_step: int,
@@ -391,7 +447,7 @@ class Trainer:
         # exactly PRNGKey(seed) — fresh_init (checkpointless restarts)
         # replays the same draw
         key = jax.random.fold_in(init_key, 1)
-        state = TrainState(params, init_adam(params))
+        state = self._placed(TrainState(params, init_adam(params)))
         hist = History()
         clock = 0.0
         self._eval_batches = [
@@ -581,6 +637,7 @@ class Trainer:
             if self.schedule is not None:
                 state, clock, key = self._handle_failures(
                     state, hist, clock, wall_step, key, failure_overhead)
+                state = self._placed(state)
 
             # 2) fused window: K steps, one dispatch, zero interior syncs.
             #    The dispatch span uses the manual clock/complete pattern —

@@ -7,6 +7,8 @@
 * ``ssd_scan``        — Mamba2 chunked SSD scan (SSM/hybrid archs).
 
 Each kernel has a pure-jnp oracle in ``ref.py`` and a jit'd dispatch wrapper
-in ``ops.py``.  Kernels are written against TPU BlockSpec/VMEM semantics and
-validated on CPU with ``interpret=True``.
+in ``ops.py``.  Kernels are written against TPU BlockSpec/VMEM semantics,
+compiled on a TPU and validated on the CPU with ``interpret=True``; every
+caller passes ``interpret`` (``ops.interpret_default()`` picks it from the
+platform).
 """

@@ -36,12 +36,19 @@ from jax.experimental import pallas as pl
 NEG_INF = -1e30
 
 
+def _tile(ref, h: int, j, blk: int) -> jnp.ndarray:
+    """Rows ``[j * blk, (j + 1) * blk)`` of head ``h`` of a (1, H, S, X)
+    block, as float32.  The slice is taken on the ref (a dynamic window of
+    the VMEM block), which the TPU lowering supports; slicing a loaded
+    value with a dynamic start it does not."""
+    start = pl.multiple_of(j * blk, blk)
+    return ref[0, h, pl.ds(start, blk), :].astype(jnp.float32)
+
+
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, blk_k: int,
                   causal: bool, window: int, scale: float, seq_len: int):
     iq = pl.program_id(2)
     q = q_ref[0, 0].astype(jnp.float32) * scale          # (blk_q, D)
-    k = k_ref[0, 0]                                      # (S, D)
-    v = v_ref[0, 0]
     blk_q, d = q.shape
     q_pos = iq * blk_q + jax.lax.broadcasted_iota(jnp.int32, (blk_q, 1), 0)
 
@@ -58,10 +65,8 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, blk_k: int,
 
     def body(j, carry):
         m, l, acc = carry
-        kj = jax.lax.dynamic_slice(k, (j * blk_k, 0), (blk_k, d)
-                                   ).astype(jnp.float32)
-        vj = jax.lax.dynamic_slice(v, (j * blk_k, 0), (blk_k, d)
-                                   ).astype(jnp.float32)
+        kj = _tile(k_ref, 0, j, blk_k)                   # (blk_k, D)
+        vj = _tile(v_ref, 0, j, blk_k)
         s = q @ kj.T                                     # (blk_q, blk_k)
         k_pos = j * blk_k + jax.lax.broadcasted_iota(jnp.int32, (1, blk_k), 1)
         mask = jnp.ones_like(s, dtype=bool)
@@ -82,7 +87,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, blk_k: int,
     acc0 = jnp.zeros((blk_q, d), jnp.float32)
     m, l, acc = jax.lax.fori_loop(lo, hi, body, (m0, l0, acc0))
     o_ref[0, 0] = (acc / (l[:, None] + 1e-30)).astype(o_ref.dtype)
-    lse_ref[0, 0] = m + jnp.log(l + 1e-30)
+    lse_ref[0, 0] = (m + jnp.log(l + 1e-30))[:, None]
 
 
 def _fwd_call(q, k, v, causal, window, blk_q, blk_k, interpret):
@@ -103,10 +108,10 @@ def _fwd_call(q, k, v, causal, window, blk_q, blk_k, interpret):
         ],
         out_specs=[
             pl.BlockSpec((1, 1, blk_q, d), lambda bi, hi, qi: (bi, hi, qi, 0)),
-            pl.BlockSpec((1, 1, blk_q), lambda bi, hi, qi: (bi, hi, qi)),
+            pl.BlockSpec((1, 1, blk_q, 1), lambda bi, hi, qi: (bi, hi, qi, 0)),
         ],
         out_shape=[jax.ShapeDtypeStruct((b, hq, s, d), q.dtype),
-                   jax.ShapeDtypeStruct((b, hq, s), jnp.float32)],
+                   jax.ShapeDtypeStruct((b, hq, s, 1), jnp.float32)],
         interpret=interpret,
     )(q, k, v)
 
@@ -121,11 +126,9 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, *,
     """dQ for one query block: walk the K/V tiles inside its horizon."""
     iq = pl.program_id(2)
     q = q_ref[0, 0].astype(jnp.float32) * scale          # (blk_q, D)
-    k = k_ref[0, 0]                                      # (S, D)
-    v = v_ref[0, 0]
     do = do_ref[0, 0].astype(jnp.float32)                # (blk_q, D)
-    lse = lse_ref[0, 0]                                  # (blk_q,)
-    delta = delta_ref[0, 0]                              # (blk_q,)
+    lse = lse_ref[0, 0]                                  # (blk_q, 1)
+    delta = delta_ref[0, 0]                              # (blk_q, 1)
     blk_q, d = q.shape
     q_pos = iq * blk_q + jax.lax.broadcasted_iota(jnp.int32, (blk_q, 1), 0)
 
@@ -140,10 +143,8 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, *,
         lo = 0
 
     def body(j, acc):
-        kj = jax.lax.dynamic_slice(k, (j * blk_k, 0), (blk_k, d)
-                                   ).astype(jnp.float32)
-        vj = jax.lax.dynamic_slice(v, (j * blk_k, 0), (blk_k, d)
-                                   ).astype(jnp.float32)
+        kj = _tile(k_ref, 0, j, blk_k)                   # (blk_k, D)
+        vj = _tile(v_ref, 0, j, blk_k)
         s = q @ kj.T                                     # (blk_q, blk_k)
         k_pos = j * blk_k + jax.lax.broadcasted_iota(jnp.int32, (1, blk_k), 1)
         mask = jnp.ones_like(s, dtype=bool)
@@ -152,9 +153,9 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, *,
         if window > 0:
             mask = mask & (k_pos > q_pos - window)
         s = jnp.where(mask, s, NEG_INF)
-        p = jnp.exp(s - lse[:, None])                    # masked -> 0
+        p = jnp.exp(s - lse)                             # masked -> 0
         dp = do @ vj.T                                   # (blk_q, blk_k)
-        ds = p * (dp - delta[:, None])
+        ds = p * (dp - delta)
         return acc + ds @ kj
 
     acc0 = jnp.zeros((blk_q, d), jnp.float32)
@@ -188,17 +189,12 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     dk = jnp.zeros((blk_k, d), jnp.float32)
     dv = jnp.zeros((blk_k, d), jnp.float32)
     for h in range(group):                               # static GQA group
-        qh = q_ref[0, h].astype(jnp.float32) * scale     # (S, D)
-        doh = do_ref[0, h].astype(jnp.float32)
-        lseh = lse_ref[0, h]                             # (S,)
-        deltah = delta_ref[0, h]
-
-        def body(i, carry):
+        def body(i, carry, h=h):
             dk_acc, dv_acc = carry
-            qi = jax.lax.dynamic_slice(qh, (i * blk_q, 0), (blk_q, d))
-            doi = jax.lax.dynamic_slice(doh, (i * blk_q, 0), (blk_q, d))
-            lsei = jax.lax.dynamic_slice(lseh, (i * blk_q,), (blk_q,))
-            deltai = jax.lax.dynamic_slice(deltah, (i * blk_q,), (blk_q,))
+            qi = _tile(q_ref, h, i, blk_q) * scale       # (blk_q, D)
+            doi = _tile(do_ref, h, i, blk_q)
+            lsei = _tile(lse_ref, h, i, blk_q)           # (blk_q, 1)
+            deltai = _tile(delta_ref, h, i, blk_q)
             q_pos = i * blk_q + jax.lax.broadcasted_iota(
                 jnp.int32, (blk_q, 1), 0)
             s = qi @ kb.T                                # (blk_q, blk_k)
@@ -208,10 +204,10 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             if window > 0:
                 mask = mask & (k_pos > q_pos - window)
             s = jnp.where(mask, s, NEG_INF)
-            p = jnp.exp(s - lsei[:, None])               # masked -> 0
+            p = jnp.exp(s - lsei)                        # masked -> 0
             dv_acc = dv_acc + p.T @ doi
             dp = doi @ vb.T
-            ds = p * (dp - deltai[:, None])
+            ds = p * (dp - deltai)
             dk_acc = dk_acc + ds.T @ qi                  # qi carries `scale`
             return dk_acc, dv_acc
 
@@ -225,7 +221,8 @@ def _bwd_call(q, k, v, o, lse, do, causal, window, blk_q, blk_k, interpret):
     hkv = k.shape[1]
     g = hq // hkv
     scale = 1.0 / math.sqrt(d)
-    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
+    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1,
+                    keepdims=True)                       # (B, Hq, S, 1)
 
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, blk_k=blk_k, causal=causal,
@@ -236,8 +233,8 @@ def _bwd_call(q, k, v, o, lse, do, causal, window, blk_q, blk_k, interpret):
             pl.BlockSpec((1, 1, s, d), lambda bi, hi, qi: (bi, hi // g, 0, 0)),
             pl.BlockSpec((1, 1, s, d), lambda bi, hi, qi: (bi, hi // g, 0, 0)),
             pl.BlockSpec((1, 1, blk_q, d), lambda bi, hi, qi: (bi, hi, qi, 0)),
-            pl.BlockSpec((1, 1, blk_q), lambda bi, hi, qi: (bi, hi, qi)),
-            pl.BlockSpec((1, 1, blk_q), lambda bi, hi, qi: (bi, hi, qi)),
+            pl.BlockSpec((1, 1, blk_q, 1), lambda bi, hi, qi: (bi, hi, qi, 0)),
+            pl.BlockSpec((1, 1, blk_q, 1), lambda bi, hi, qi: (bi, hi, qi, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, blk_q, d),
                                lambda bi, hi, qi: (bi, hi, qi, 0)),
@@ -256,8 +253,8 @@ def _bwd_call(q, k, v, o, lse, do, causal, window, blk_q, blk_k, interpret):
             pl.BlockSpec((1, 1, blk_k, d), lambda bi, hi, ki: (bi, hi, ki, 0)),
             pl.BlockSpec((1, 1, blk_k, d), lambda bi, hi, ki: (bi, hi, ki, 0)),
             pl.BlockSpec((1, g, s, d), lambda bi, hi, ki: (bi, hi, 0, 0)),
-            pl.BlockSpec((1, g, s), lambda bi, hi, ki: (bi, hi, 0)),
-            pl.BlockSpec((1, g, s), lambda bi, hi, ki: (bi, hi, 0)),
+            pl.BlockSpec((1, g, s, 1), lambda bi, hi, ki: (bi, hi, 0, 0)),
+            pl.BlockSpec((1, g, s, 1), lambda bi, hi, ki: (bi, hi, 0, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, 1, blk_k, d), lambda bi, hi, ki: (bi, hi, ki, 0)),
@@ -298,7 +295,7 @@ _flash.defvjp(_flash_fwd, _flash_bwd)
                                              "blk_k", "interpret"))
 def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
                     causal: bool = True, window: int = 0, blk_q: int = 128,
-                    blk_k: int = 128, interpret: bool = True) -> jnp.ndarray:
+                    blk_k: int = 128, interpret: bool) -> jnp.ndarray:
     """q: (B, Hq, S, D); k/v: (B, Hkv, S, D) with Hq % Hkv == 0."""
     b, hq, s, d = q.shape
     hkv = k.shape[1]

@@ -1,9 +1,8 @@
 """Jit'd dispatch wrappers around the Pallas kernels.
 
-``interpret`` defaults to True (this container is CPU-only; on a real TPU
-deployment set ``REPRO_PALLAS_INTERPRET=0`` to run the compiled kernels).
-The flag is read at call time, so flipping the environment variable inside
-a process (tests, benchmarks) takes effect without re-importing.  The
+The kernels are compiled for the TPU and interpreted everywhere else: the
+choice follows the platform JAX runs on (:func:`interpret_default`), read
+at call time, so no environment variable or caller has to know it.  The
 compiled path is fully trainable: ``flash_attention`` carries a
 recompute-based custom VJP (see ``kernels/flash_attention.py``), so
 reverse-mode autodiff never needs the interpreter.
@@ -13,8 +12,7 @@ kernel layouts ((B, H, S, D)).
 """
 from __future__ import annotations
 
-import os
-
+import jax
 import jax.numpy as jnp
 
 from repro.kernels.flash_attention import flash_attention as _flash
@@ -23,8 +21,9 @@ from repro.kernels.stage_merge import stage_merge as _merge
 
 
 def interpret_default() -> bool:
-    """Whether kernels run in interpret mode (REPRO_PALLAS_INTERPRET != 0)."""
-    return os.environ.get("REPRO_PALLAS_INTERPRET", "1") != "0"
+    """Whether the kernels run in Pallas interpret mode: everywhere except
+    on a TPU, where they are compiled."""
+    return jax.default_backend() != "tpu"
 
 
 def stage_merge(x: jnp.ndarray, y: jnp.ndarray, ca, cb) -> jnp.ndarray:

@@ -30,7 +30,7 @@ def _merge_kernel(w_ref, x_ref, y_ref, o_ref):
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def stage_merge_flat(x: jnp.ndarray, y: jnp.ndarray, ca: jnp.ndarray,
-                     cb: jnp.ndarray, *, interpret: bool = True) -> jnp.ndarray:
+                     cb: jnp.ndarray, *, interpret: bool) -> jnp.ndarray:
     """x, y: 2D (rows, TILE_COLS) with rows % TILE_ROWS == 0."""
     rows, cols = x.shape
     assert cols == TILE_COLS and rows % TILE_ROWS == 0, x.shape
@@ -51,7 +51,7 @@ def stage_merge_flat(x: jnp.ndarray, y: jnp.ndarray, ca: jnp.ndarray,
 
 
 def stage_merge(x: jnp.ndarray, y: jnp.ndarray, ca, cb, *,
-                interpret: bool = True) -> jnp.ndarray:
+                interpret: bool) -> jnp.ndarray:
     """Arbitrary-shape wrapper: flatten -> pad -> tile -> kernel -> unpad."""
     shape, dtype = x.shape, x.dtype
     n = x.size

@@ -394,8 +394,9 @@ def main() -> None:
                     help="CI mesh-regression guard: construct every "
                          "production/pipeline mesh variant and lower one "
                          "small training pair (no compile, no cost pass) — "
-                         "fails fast on mesh API breakage like the "
-                         "jax.sharding.AxisType pin mismatch")
+                         "fails fast when the installed jax changes how "
+                         "jax.make_mesh / jax.sharding.AxisType build a "
+                         "mesh")
     ap.add_argument("--out", default="")
     args = ap.parse_args()
 

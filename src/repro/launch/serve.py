@@ -19,6 +19,7 @@ import numpy as np
 from repro.configs import ARCHS, PAPER_MODELS, get_config, reduced
 from repro.telemetry import log
 from repro.data.pipeline import SyntheticLM, batch_for
+from repro.launch.compile_cache import configure_compile_cache
 from repro.models.model import build_model
 
 
@@ -34,6 +35,7 @@ def main() -> None:
     ap.add_argument("--window", type=int, default=0,
                     help=">0: SWA ring-cache serving (long-context mode)")
     args = ap.parse_args()
+    configure_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:

@@ -15,6 +15,7 @@ from __future__ import annotations
 
 import argparse
 import os
+from typing import Optional, Sequence
 
 from repro import telemetry
 from repro.telemetry import log
@@ -24,13 +25,14 @@ from repro.core.failures import FailureSchedule
 from repro.core.trainer import Trainer
 from repro.core.walltime import WallClockModel
 from repro.data.pipeline import batch_for, make_batches, SyntheticLM
+from repro.launch.compile_cache import configure_compile_cache
 from repro.models.model import build_model
 from repro.recovery import available_strategies
 
 import numpy as np
 
 
-def main() -> None:
+def main(argv: Optional[Sequence[str]] = None) -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="paper-llama-124m",
                     choices=sorted(ARCHS) + sorted(PAPER_MODELS))
@@ -61,8 +63,8 @@ def main() -> None:
                          "window (1 = eager per-step loop; see docs/perf.md)")
     ap.add_argument("--backend", default="host", choices=["host", "spmd"],
                     help="'spmd' runs the pipeline-parallel shard_map "
-                         "backend (one device per stage; forces host "
-                         "devices when none are configured — see "
+                         "backend (one device per stage; under "
+                         "JAX_PLATFORMS=cpu it forces host devices — see "
                          "docs/pipeline.md)")
     ap.add_argument("--reduced", action="store_true",
                     help="CPU-sized variant of the same family")
@@ -82,7 +84,8 @@ def main() -> None:
                          "(trace.json, loadable in Perfetto) into "
                          "--telemetry-dir")
     ap.add_argument("--quiet", action="store_true")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
+    configure_compile_cache()
 
     rec = None
     if args.telemetry_dir:
@@ -110,8 +113,9 @@ def main() -> None:
         stages = max(d for d in range(1, cfg.num_layers + 1)
                      if cfg.num_layers % d == 0 and d <= stages)
     if args.backend == "spmd":
-        # one device per stage; best-effort — only works before jax's first
-        # backend query, otherwise launch with XLA_FLAGS set in the shell
+        # one device per stage: under JAX_PLATFORMS=cpu ask for virtual host
+        # devices (only works before jax's first backend query); on an
+        # accelerator the stages map onto its chips
         from repro.launch.mesh import force_host_devices
         force_host_devices(stages)
     seq = args.seq or min(cfg.max_seq_len, 512)

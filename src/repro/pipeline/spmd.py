@@ -13,7 +13,7 @@ Three layers of machinery live here:
 * :func:`pipeline_loss` — the forward pipeline loss (parity oracle for the
   subprocess check; kept API-stable).
 * :func:`make_spmd_fused_train_step` — the full training step: one
-  ``shard_map`` wrapping a fused ``lax.scan`` window of
+  ``shard_map`` wrapping a fused loop window of
   grad -> psum -> Adam steps.  Per-device autodiff differentiates the
   *pre-psum* local loss (the global loss is the sum of per-device partial
   losses, so local grads of the tower slice are exact and only the
@@ -40,27 +40,8 @@ from typing import Any, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import Mesh, PartitionSpec as P
-
-try:  # jax >= 0.5 exports shard_map at the top level
-    shard_map = jax.shard_map
-except AttributeError:  # older jax (the pinned 0.4.37): experimental
-    from jax.experimental.shard_map import shard_map
-
-# the static replication checker predates grad-inside-shard_map over
-# scanned collectives; disable it under whatever name this JAX spells it
-# (check_rep on 0.4.x, check_vma later, absent eventually) — semantics are
-# unaffected either way, the flag only controls a static check
-import inspect as _inspect
-_NO_CHECK_KW: Dict[str, Any] = {}
-try:
-    _smap_params = _inspect.signature(shard_map).parameters
-    if "check_rep" in _smap_params:
-        _NO_CHECK_KW = {"check_rep": False}
-    elif "check_vma" in _smap_params:
-        _NO_CHECK_KW = {"check_vma": False}
-except (TypeError, ValueError):  # pragma: no cover — exotic wrappers
-    pass
+from jax import shard_map
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro import telemetry
 from repro.config import ModelConfig, OptimizerConfig
@@ -92,6 +73,18 @@ def opt_pipeline_specs(pspecs: Params) -> OptState:
     """Adam moments mirror the param sharding; the step counter is
     replicated."""
     return OptState(m=pspecs, v=pspecs, step=P())
+
+
+def pipeline_state_shardings(mesh: Mesh, state: Tuple[Params, OptState],
+                             ) -> Tuple[Params, OptState]:
+    """``NamedSharding``s of ``(params, opt_state)`` as the fused step keeps
+    them on ``mesh``: the specs of :func:`param_pipeline_specs` and
+    :func:`opt_pipeline_specs` (``state`` may hold shapes only)."""
+    params, _ = state
+    pspecs = param_pipeline_specs(params, mesh.shape["stage"])
+    return jax.tree.map(lambda spec: NamedSharding(mesh, spec),
+                        (pspecs, opt_pipeline_specs(pspecs)),
+                        is_leaf=lambda x: isinstance(x, P))
 
 
 def _apply_local_blocks(cfg: ModelConfig, blocks_local: Params,
@@ -297,9 +290,9 @@ def make_spmd_fused_train_step(model, opt_cfg: OptimizerConfig,
       stage order: the swapped tower is built by hopping neighbour slices
       one stage via ppermute (:func:`_swapped_blocks`).
 
-    The static replication checker is disabled (``check_rep``/``check_vma``
-    per JAX version): it predates grad-inside-shard_map over scanned
-    collectives; semantics are unaffected (it is a static check only).
+    The static replication checker is disabled (``check_vma=False``): it
+    rejects grad-inside-shard_map over looped collectives; semantics are
+    unaffected (it is a static check only).
     """
     cfg = model.cfg
     assert cfg.arch_type in ("dense", "moe"), (
@@ -310,7 +303,7 @@ def make_spmd_fused_train_step(model, opt_cfg: OptimizerConfig,
     swap_pairs = _swap_block_perm(K) if use_swap else []
     # deferred: trainer imports this module lazily, never the reverse at
     # module scope
-    from repro.core.trainer import _jit_donated
+    from repro.core.trainer import _jit_donated, window_loop
 
     def local_loss(params, batch):
         cparams = L.cast_tree(params, cfg.dtype)
@@ -337,7 +330,7 @@ def make_spmd_fused_train_step(model, opt_cfg: OptimizerConfig,
         total = ce + cfg.moe.router_aux_coef * aux
         return total, (ce, aux)
 
-    def per_device(params, opt_state, stacked, lr_scale):
+    def per_device(params, opt_state, stacked, lr_scale, n):
         my = jax.lax.axis_index("stage")
 
         def body(carry, batch):
@@ -375,18 +368,18 @@ def make_spmd_fused_train_step(model, opt_cfg: OptimizerConfig,
             return (params, opt_state, ls_next), ring
 
         carry0 = (params, opt_state, jnp.asarray(lr_scale, jnp.float32))
-        (params, opt_state, ls), outs = jax.lax.scan(body, carry0, stacked)
+        (params, opt_state, ls), outs = window_loop(body, carry0, stacked, n)
         return params, opt_state, ls, outs
 
     @_jit_donated
-    def fused_step(params, opt_state, stacked, lr_scale):
+    def fused_step(params, opt_state, stacked, lr_scale, n):
         pspecs = param_pipeline_specs(params, K)
         f = shard_map(
             per_device, mesh=mesh,
-            in_specs=(pspecs, opt_pipeline_specs(pspecs), P(), P()),
+            in_specs=(pspecs, opt_pipeline_specs(pspecs), P(), P(), P()),
             out_specs=(pspecs, opt_pipeline_specs(pspecs), P(), P()),
-            **_NO_CHECK_KW)
-        return f(params, opt_state, stacked, lr_scale)
+            check_vma=False)
+        return f(params, opt_state, stacked, lr_scale, n)
 
     # host-side dispatch span (repro.telemetry): times the enqueue of the
     # sharded window, never runs inside traced code.  ``functools.wraps``

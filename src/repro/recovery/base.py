@@ -38,7 +38,7 @@ capability flags (drive trainer wiring — the trainer never looks at names)
                              stage order on half the batch
 
 fused hot-path contract (the trainer fuses failure-free iteration runs into
-a single on-device ``lax.scan`` window and only drains state at window
+a single on-device loop window and only drains state at window
 boundaries — see ``docs/perf.md``)
   ``after_step_horizon(step)`` — how many iterations may be fused before
                              ``after_step`` must observe host state again

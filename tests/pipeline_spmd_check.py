@@ -1,5 +1,6 @@
 """Standalone SPMD pipeline verification — run in a subprocess with
-4 host devices (the test wrapper sets XLA_FLAGS).  Asserts:
+4 host CPU devices (this script sets JAX_PLATFORMS and XLA_FLAGS before
+importing jax).  Asserts:
 
 1. pipeline_loss == reference model.loss (same params/batch),
 2. grads through the pipeline == reference grads,
@@ -13,16 +14,20 @@
 5. a short Trainer training run on ``backend="spmd"`` reproduces the
    host-loop backend's loss curve within tolerance for checkfree AND
    checkfree_plus, with a mid-run middle-stage and an edge-stage failure
-   recovered in-mesh.
+   recovered in-mesh, compiling one executable per window size.
 """
 import os
 
+# four virtual CPU devices, always: the four-chip path is
+# `chip_smoke.py --four-chips`, never this script
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
 
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 
+from repro.analysis.runtime import compiled_variant_count  # noqa: E402
 from repro.config import (ModelConfig, OptimizerConfig,  # noqa: E402
                           RecoveryConfig, TrainConfig)
 from repro.configs import reduced  # noqa: E402
@@ -147,13 +152,28 @@ for key in ("loss", "ce", "aux", "grad_norm", "lr"):
                                atol=1e-6, err_msg=key)
 np.testing.assert_allclose(np.asarray(hring["omegas"]),
                            np.asarray(sring["omegas"]), rtol=2e-3)
-for (ka, a), (kb, b) in zip(
-        sorted(jax.tree_util.tree_leaves_with_path(hp),
-               key=lambda kv: str(kv[0])),
-        sorted(jax.tree_util.tree_leaves_with_path(sp),
-               key=lambda kv: str(kv[0]))):
-    np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-6,
-                               err_msg=str(ka))
+# Adam's first moment is linear in the (clipped) gradients: hold it to a
+# tight bound, relative to each leaf's largest entry.
+def leaves_by_path(tree):
+    return sorted(jax.tree_util.tree_leaves_with_path(tree),
+                  key=lambda kv: str(kv[0]))
+
+
+for (ka, a), (_, b) in zip(leaves_by_path(ho.m), leaves_by_path(so.m)):
+    a, b = np.asarray(a), np.asarray(b)
+    np.testing.assert_allclose(a, b, atol=1e-5 * np.abs(a).max(),
+                               err_msg=f"adam m {ka}")
+# params after one Adam step: the two backends sum the same gradients in a
+# different order (one reduction over the whole tower vs per-stage partials
+# plus a psum), and Adam's first step divides each element by its own
+# magnitude (m / (sqrt(v) + eps)), so an element whose gradient is near
+# zero turns a last-bit gradient difference into a visible share of one
+# update.  Bound the difference by 10% of one update (lr); the moment check
+# above is what holds the gradients themselves to float32 agreement.
+lr_now = float(hring["lr"][0])
+for (ka, a), (_, b) in zip(leaves_by_path(hp), leaves_by_path(sp)):
+    np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                               atol=0.1 * lr_now, err_msg=str(ka))
 print("swap-schedule fused step matches host backend "
       f"(loss {float(hring['loss'][0]):.6f})")
 
@@ -180,7 +200,12 @@ def train(backend, strategy, events):
                       schedule=ForcedSchedule(events), backend=backend)
     if backend == "spmd" and strategy != "none":
         assert trainer.strategy._in_mesh_recover is not None
-    return trainer.run(make_batches(train_cfg, batch=8, seq=32, seed=0))
+    out = trainer.run(make_batches(train_cfg, batch=8, seq=32, seed=0))
+    # one executable per window size: the initial state is placed on the
+    # mesh like every later one (no second compile for one-device inputs)
+    assert compiled_variant_count(trainer.fused_step) == \
+        len(trainer.dispatched_buckets), backend
+    return out
 
 
 # checkfree: mid-run middle-stage failure; checkfree_plus additionally

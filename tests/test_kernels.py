@@ -3,15 +3,30 @@
 Every kernel is swept over shapes and dtypes and asserted allclose against
 ``repro.kernels.ref`` (the definitional semantics).
 """
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.kernels import flash_attention as FA
 from repro.kernels import ref as R
-from repro.kernels.flash_attention import flash_attention
-from repro.kernels.ssd_scan import ssd_scan
-from repro.kernels.stage_merge import stage_merge
+from repro.kernels import ssd_scan as SSD
+from repro.kernels import stage_merge as SM
+
+# the oracles check the kernels' semantics in interpret mode (the compiled
+# TPU lowering is checked by tests/test_tpu_compile.py)
+flash_attention = functools.partial(FA.flash_attention, interpret=True)
+ssd_scan = functools.partial(SSD.ssd_scan, interpret=True)
+stage_merge = functools.partial(SM.stage_merge, interpret=True)
+
+
+def test_interpret_mode_follows_the_platform():
+    """Compiled on a TPU, interpreted everywhere else; never from an
+    environment variable."""
+    from repro.kernels.ops import interpret_default
+    assert interpret_default() is (jax.default_backend() != "tpu")
 
 TOL = {jnp.float32: dict(atol=2e-5, rtol=2e-5),
        jnp.bfloat16: dict(atol=3e-2, rtol=3e-2)}

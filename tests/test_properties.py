@@ -85,7 +85,7 @@ def test_merge_convexity_property(n, w, seed):
     k1, k2 = jax.random.split(jax.random.PRNGKey(seed))
     x = jax.random.normal(k1, (n,), jnp.float32)
     y = jax.random.normal(k2, (n,), jnp.float32)
-    got = np.asarray(stage_merge(x, y, w, 1.0 - w))
+    got = np.asarray(stage_merge(x, y, w, 1.0 - w, interpret=True))
     lo = np.minimum(np.asarray(x), np.asarray(y)) - 1e-5
     hi = np.maximum(np.asarray(x), np.asarray(y)) + 1e-5
     assert (got >= lo).all() and (got <= hi).all()

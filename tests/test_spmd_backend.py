@@ -1,9 +1,11 @@
-"""Single-device tests for the SPMD backend's host-side machinery: the
-version-compat mesh construction (the jax-0.4.37 ``AxisType`` regression),
-the GPipe tick permutations, the swap-schedule block hops, the swap-loss
-metrics fix, backend selection, and the Adam mesh-global grad-norm
-override.  Everything that needs >1 device runs in the subprocess check
-(``pipeline_spmd_check.py``)."""
+"""Single-device tests for the SPMD backend's host-side machinery: mesh
+construction (``launch/mesh.make_mesh``) and the CPU-only host-device
+forcing, the GPipe tick permutations, the swap-schedule block hops, the
+swap-loss metrics fix, backend selection, and the Adam mesh-global
+grad-norm override.  Everything that needs >1 device runs in the
+subprocess check (``pipeline_spmd_check.py``)."""
+import os
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -14,7 +16,8 @@ from repro.config import ModelConfig, OptimizerConfig, RecoveryConfig, \
 from repro.core.stages import StagePartition
 from repro.core.swap import swap_permutation
 from repro.core.trainer import Trainer, _make_loss_fn, _permute_tower
-from repro.launch.mesh import make_compat_mesh, make_host_pipeline_mesh
+from repro.launch.mesh import (force_host_devices, make_host_pipeline_mesh,
+                               make_mesh)
 from repro.models.model import build_model
 from repro.optim.adam import adam_update, global_norm, init_adam
 from repro.pipeline.spmd import _swap_block_perm, _tick_perm
@@ -26,30 +29,44 @@ CFG = ModelConfig(
 
 
 # ---------------------------------------------------------------------------
-# mesh compat (launch/mesh.py under the pinned JAX)
+# mesh construction (launch/mesh.py)
 # ---------------------------------------------------------------------------
 
 def test_make_compat_mesh_builds_on_this_jax():
-    """The AxisType regression guard: construction must work whether or not
-    jax.sharding.AxisType exists (it does not on the pinned 0.4.37)."""
-    mesh = make_compat_mesh((1,), ("stage",))
+    """One construction path on the installed JAX: an Auto-typed mesh over
+    the visible devices."""
+    mesh = make_mesh((1,), ("stage",))
     assert mesh.axis_names == ("stage",)
     assert mesh.devices.shape == (1,)
+    assert mesh.axis_types == (jax.sharding.AxisType.Auto,)
 
 
 def test_make_compat_mesh_explicit_devices():
-    mesh = make_compat_mesh((1,), ("stage",), devices=jax.devices())
+    mesh = make_mesh((1,), ("stage",), devices=jax.devices())
     assert mesh.devices[0] == jax.devices()[0]
 
 
 def test_make_compat_mesh_rejects_device_shortfall():
-    with pytest.raises(AssertionError, match="needs 2 devices"):
-        make_compat_mesh((2,), ("stage",), devices=jax.devices()[:1])
+    with pytest.raises(ValueError, match="needs 2 devices"):
+        make_mesh((2,), ("stage",), devices=jax.devices()[:1])
+
+
+@pytest.mark.parametrize("platforms,forced", [("cpu", True), ("", False),
+                                              ("tpu", False)])
+def test_force_host_devices_only_on_cpu(monkeypatch, platforms, forced):
+    """Virtual host devices are asked for only under JAX_PLATFORMS=cpu: on
+    an accelerator a missing chip must surface as the mesh error."""
+    monkeypatch.setenv("JAX_PLATFORMS", platforms)
+    monkeypatch.setenv("XLA_FLAGS", "")
+    force_host_devices(4)
+    assert ("device_count=4" in os.environ["XLA_FLAGS"]) == forced
 
 
 def test_host_pipeline_mesh_explains_device_shortfall():
-    with pytest.raises(RuntimeError, match="one device per stage"):
+    with pytest.raises(RuntimeError, match="one device per stage") as err:
         make_host_pipeline_mesh(max(len(jax.devices()) + 1, 64))
+    # the message names the platform it found
+    assert f"{jax.devices()[0].platform} device(s)" in str(err.value)
 
 
 def test_trainer_spmd_backend_surfaces_mesh_error():

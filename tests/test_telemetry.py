@@ -511,3 +511,21 @@ def test_bench_results_carry_env_fingerprint(tmp_path, monkeypatch):
     path = common.save_json("kept.json", {"env": {"jax": "pinned"}})
     with open(path) as f:
         assert json.load(f)["env"] == {"jax": "pinned"}
+    # the interpret flag is the platform's choice, recorded as a bool
+    assert fp["pallas_interpret"] is (fp["backend"] != "tpu")
+
+
+def test_bench_run_cache_is_keyed_on_the_device():
+    """A CPU result must never be served for a chip run of the same
+    parameters: the run cache key carries platform, kind and count."""
+    sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
+    from benchmarks import common
+    params = dict(strategy="checkfree", rate=0.1, scenario=None, steps=8,
+                  seed=0, ckpt_every=4, failure_seed=1, lr=1e-3)
+    cpu = common.env_fingerprint()
+    keys = {common._cache_key(common.run_key(env, **params))
+            for env in (cpu, dict(cpu, backend="tpu"),
+                        dict(cpu, device_kind="TPU v5 lite"),
+                        dict(cpu, device_count=cpu["device_count"] + 3))}
+    assert len(keys) == 4
+    assert common.run_key(cpu, **params)["platform"] == cpu["backend"]
