@@ -1,0 +1,8 @@
+"""Share of the measured window in which no operation ran on the device,
+in percent (device trace, averaged over the chips)."""
+
+
+def read(ctx):
+    if ctx.trace is None or ctx.window_s <= 0:
+        return None
+    return 100.0 * (1.0 - ctx.trace["busy_s"] / ctx.window_s)
