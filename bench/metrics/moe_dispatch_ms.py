@@ -1,0 +1,9 @@
+"""Device time of the operations under the program's ``moe_dispatch``
+scope (router, softmax, top-k dispatch, the dispatch and combine einsums)
+inside the dispatched windows, per training step (device trace, averaged
+over the chips)."""
+from bench.devscope import scope_ms_per_step
+
+
+def read(ctx):
+    return scope_ms_per_step(ctx, "moe_dispatch")

@@ -108,6 +108,7 @@ class StagePartition:
         return out
 
     # ---- per-stage gradient norms (Alg. 1's omega) ---------------------
+    @jax.named_scope("stage_omegas")
     def stage_grad_sqnorms(self, grads: Params) -> jnp.ndarray:
         """omega_i = ||grad W_{s,i}||^2, a (num_stages,) vector.
 

@@ -64,6 +64,7 @@ from repro.recovery import FailureContext, RecoveryStrategy, make_strategy
 Params = Any
 
 
+@jax.named_scope("tower_swap")
 def _permute_tower(params: Params, tower_key: str, idx: jnp.ndarray) -> Params:
     out = dict(params)
     out[tower_key] = jax.tree.map(lambda a: jnp.take(a, idx, axis=0),
@@ -97,6 +98,7 @@ def _make_loss_fn(model: Model, part: StagePartition, use_swap: bool,
     return loss_fn
 
 
+@jax.named_scope("window_loop")
 def window_loop(step: Callable, carry: Any, stacked: Any, n: jnp.ndarray,
                 ) -> Tuple[Any, Any]:
     """``lax.scan(step, carry, stacked)`` with the trip count ``n`` passed
@@ -455,15 +457,11 @@ class Trainer:
             for eb in eval_batches] if eval_batches else None
         self._prefetch = WindowPrefetcher(batches)
 
-        # per-family FLOP estimate (6 * active params * tokens for training)
-        # — what the report CLI turns into an MFU figure
-        tokens = tcfg.global_batch * tcfg.seq_len
         telemetry.emit(
             "run_start", arch=self.model.cfg.name, strategy=strategy.name,
             backend=self.backend, steps=tcfg.steps,
             num_stages=self.rcfg.num_stages,
-            flops_per_step=6 * self.model.cfg.active_param_count() * tokens,
-            tokens_per_step=tokens)
+            tokens_per_step=tcfg.global_batch * tcfg.seq_len)
 
         wall_step = 0
         max_wall = tcfg.steps * 10  # safety bound for rollback-heavy runs
@@ -635,19 +633,27 @@ class Trainer:
 
             # 1) failures at this boundary
             if self.schedule is not None:
-                state, clock, key = self._handle_failures(
-                    state, hist, clock, wall_step, key, failure_overhead)
-                state = self._placed(state)
+                with telemetry.span("failures", cat="trainer",
+                                    wall_step=wall_step):
+                    state, clock, key = self._handle_failures(
+                        state, hist, clock, wall_step, key, failure_overhead)
 
             # 2) fused window: K steps, one dispatch, zero interior syncs.
             #    The dispatch span uses the manual clock/complete pattern —
             #    a `with` block around a donating call would make the
             #    donation-liveness lint see the donated-arg read and the
             #    re-dispatch as one statement (and it is a no-op two-call
-            #    path when telemetry is disabled anyway).
-            k = self._window_size(wall_step, state.effective_step, max_wall)
-            stacked = self._prefetch.take(state.effective_step, k)
+            #    path when telemetry is disabled anyway).  The anchor ties
+            #    the recorder's clock to the profiler's once per window.
+            with telemetry.span("window_prepare", cat="trainer",
+                                wall_step=wall_step):
+                if self.schedule is not None:
+                    state = self._placed(state)
+                k = self._window_size(wall_step, state.effective_step,
+                                      max_wall)
+                stacked = self._prefetch.take(state.effective_step, k)
             t0 = telemetry.clock()
+            telemetry.anchor()
             params, opt_state, lr_scale, outs = self.fused_step(
                 state.params, state.opt_state,
                 {kk: jnp.asarray(v) for kk, v in stacked.items()},
@@ -677,51 +683,54 @@ class Trainer:
                                state.effective_step + k)
 
             # 4) host-side bookkeeping, per wall iteration, in the exact
-            #    order the eager loop used (telemetry -> pricing -> hist)
-            stretch = 0.0
-            for i in range(k):
-                if i > 0 and observed_rate is not None:
-                    strategy.observe_environment(
-                        observed_rate(wall_step + i))
-                if iter_factor_active is not None and \
-                        len(self._slots) < self.rcfg.num_stages:
-                    # shrunk layout: pace by the surviving slots only —
-                    # departed slots no longer stall the pipeline
-                    factor = iter_factor_active(wall_step + i, self._slots)
-                elif iter_factor is not None:
-                    factor = iter_factor(wall_step + i)
-                else:
-                    factor = 1.0
-                clock += strategy.iteration_cost() * factor
-                stretch += factor
-                hist.steps.append(state.effective_step - k + i + 1)
-                hist.wall_time.append(clock)
-                hist.loss.append(float(losses[i]))
-            telemetry.emit("step_window", wall_step=wall_step, k=k,
-                           effective_step=state.effective_step,
-                           loss=float(losses[-1]), clock_s=clock,
-                           stretch=stretch / k)
+            #    order the eager loop used (telemetry -> pricing -> hist);
+            #    steps 4-5 open the boundary at wall step `wall_step + k`
+            with telemetry.span("window_bookkeeping", cat="trainer",
+                                wall_step=wall_step + k):
+                stretch = 0.0
+                for i in range(k):
+                    if i > 0 and observed_rate is not None:
+                        strategy.observe_environment(
+                            observed_rate(wall_step + i))
+                    if iter_factor_active is not None and \
+                            len(self._slots) < self.rcfg.num_stages:
+                        # shrunk layout: pace by the surviving slots only —
+                        # departed slots no longer stall the pipeline
+                        factor = iter_factor_active(wall_step + i, self._slots)
+                    elif iter_factor is not None:
+                        factor = iter_factor(wall_step + i)
+                    else:
+                        factor = 1.0
+                    clock += strategy.iteration_cost() * factor
+                    stretch += factor
+                    hist.steps.append(state.effective_step - k + i + 1)
+                    hist.wall_time.append(clock)
+                    hist.loss.append(float(losses[i]))
+                telemetry.emit("step_window", wall_step=wall_step, k=k,
+                               effective_step=state.effective_step,
+                               loss=float(losses[-1]), clock_s=clock,
+                               stretch=stretch / k)
 
-            # 5) strategy bookkeeping on the drained state (checkpoint
-            #    saves, adaptive windows...); interior steps were certified
-            #    skippable by after_step_horizon
-            strategy.after_step(state, hist)
-            if replay is not None:
-                self._prefetch.evict_below(state.effective_step - replay)
+                # 5) strategy bookkeeping on the drained state (checkpoint
+                #    saves, adaptive windows...); interior steps were certified
+                #    skippable by after_step_horizon
+                strategy.after_step(state, hist)
+                if replay is not None:
+                    self._prefetch.evict_below(state.effective_step - replay)
 
-            if self._eval_batches and \
-                    state.effective_step % tcfg.eval_every == 0:
-                el = float(np.mean([
-                    float(self.eval_step(state.params, eb))
-                    for eb in self._eval_batches]))
-                hist.eval_loss.append((state.effective_step, clock, el))
-                telemetry.emit("eval", step=state.effective_step, loss=el,
-                               clock_s=clock)
-                if verbose:
-                    telemetry.log(
-                        f"  step {state.effective_step:4d} "
-                        f"wall {clock/3600:7.2f}h loss "
-                        f"{losses[-1]:.3f} eval {el:.3f}")
+                if self._eval_batches and \
+                        state.effective_step % tcfg.eval_every == 0:
+                    el = float(np.mean([
+                        float(self.eval_step(state.params, eb))
+                        for eb in self._eval_batches]))
+                    hist.eval_loss.append((state.effective_step, clock, el))
+                    telemetry.emit("eval", step=state.effective_step, loss=el,
+                                   clock_s=clock)
+                    if verbose:
+                        telemetry.log(
+                            f"  step {state.effective_step:4d} "
+                            f"wall {clock/3600:7.2f}h loss "
+                            f"{losses[-1]:.3f} eval {el:.3f}")
             wall_step += k
 
         return state, hist, clock, wall_step

@@ -10,17 +10,27 @@ compile.
 from __future__ import annotations
 
 import os
+import re
 
 import jax
 
-CHECKOUT_CACHE_DIR = os.path.join(
-    os.path.dirname(os.path.dirname(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))))), ".jax_cache")
+CHECKOUT_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.dirname(os.path.abspath(__file__)))))
+CHECKOUT_CACHE_DIR = os.path.join(CHECKOUT_ROOT, ".jax_cache")
 
 
 def configure_compile_cache() -> str:
     """Point JAX's persistent compilation cache at its fixed directory and
-    return that directory."""
+    return that directory.
+
+    The cache key keeps the programs' metadata: by default JAX strips it,
+    and a program whose named scopes (the op names a device trace shows)
+    changed would be served the executable compiled before the change.
+    Source files enter the metadata relative to the checkout, so that the
+    same code checked out elsewhere still hits the cache."""
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
+    jax.config.update("jax_hlo_source_file_canonicalization_regex",
+                      "^" + re.escape(CHECKOUT_ROOT + os.sep))
     env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if env:
         return env

@@ -23,6 +23,7 @@ Params = Dict[str, Any]
 # initializers
 # ---------------------------------------------------------------------------
 
+@jax.named_scope("param_cast")
 def cast_tree(tree: Any, dtype) -> Any:
     """Cast every floating leaf to ``dtype`` (compute-dtype entry cast)."""
     dt = jnp.dtype(dtype)
@@ -146,6 +147,7 @@ def swa_mask(s: int, t: int, window: int, offset: int = 0) -> jnp.ndarray:
     return (kpos <= qpos) & (kpos > qpos - window)
 
 
+@jax.named_scope("attention")
 def attention(p: Params, x: jnp.ndarray, positions: jnp.ndarray,
               cfg: ModelConfig, *, mask: Optional[jnp.ndarray],
               kv: Optional[Tuple[jnp.ndarray, jnp.ndarray]] = None,
@@ -292,6 +294,7 @@ def init_embedding(key: jax.Array, vocab: int, d: int, dtype) -> Params:
     return {"table": embed_init(key, (vocab, d), dtype)}
 
 
+@jax.named_scope("embed")
 def embed(p: Params, tokens: jnp.ndarray, scale: bool = False) -> jnp.ndarray:
     x = jnp.take(p["table"], tokens, axis=0)
     if scale:  # gemma-style sqrt(d) embedding scale
@@ -299,6 +302,7 @@ def embed(p: Params, tokens: jnp.ndarray, scale: bool = False) -> jnp.ndarray:
     return x
 
 
+@jax.named_scope("logits_loss")
 def unembed(p: Params, x: jnp.ndarray, softcap: float = 0.0) -> jnp.ndarray:
     logits = x @ p["table"].T
     if softcap > 0:
@@ -310,6 +314,7 @@ def init_unembed(key: jax.Array, d: int, vocab: int, dtype) -> Params:
     return {"w": dense_init(key, (d, vocab), dtype)}
 
 
+@jax.named_scope("logits_loss")
 def unembed_w(p: Params, x: jnp.ndarray, softcap: float = 0.0) -> jnp.ndarray:
     logits = x @ p["w"]
     if softcap > 0:
@@ -355,6 +360,7 @@ def _token_nll_bwd(res, g):
 _token_nll.defvjp(_token_nll_fwd, _token_nll_bwd)
 
 
+@jax.named_scope("logits_loss")
 def cross_entropy(logits: jnp.ndarray, labels: jnp.ndarray,
                   mask: Optional[jnp.ndarray] = None) -> jnp.ndarray:
     """Mean token cross-entropy (fp32 accumulation). labels: int32 (B, S)."""

@@ -104,17 +104,20 @@ def moe_mlp(p: Params, x: jnp.ndarray, cfg: ModelConfig,
     gdim = (b * s) // tg
     xg = x.reshape(gdim, tg, d)
 
-    logits = (xg.astype(jnp.float32) @ p["router"].astype(jnp.float32))
-    gates = jax.nn.softmax(logits, axis=-1)                 # (G, T, E)
-    capacity = max(1, int(math.ceil(tg * m.top_k / m.num_experts
-                                    * m.capacity_factor)))
-    dispatch, combine, aux = topk_dispatch(gates, m.top_k, capacity, x.dtype)
-
-    ein = jnp.einsum("gtd,gtec->gecd", xg, dispatch)        # (G, E, C, d)
-    h = L._act(cfg.act, jnp.einsum("gecd,edf->gecf", ein, p["w_gate"]))
-    h = h * jnp.einsum("gecd,edf->gecf", ein, p["w_up"])
-    eout = jnp.einsum("gecf,efd->gecd", h, p["w_down"])     # (G, E, C, d)
-    out = jnp.einsum("gecd,gtec->gtd", eout, combine)
+    with jax.named_scope("moe_dispatch"):
+        logits = (xg.astype(jnp.float32) @ p["router"].astype(jnp.float32))
+        gates = jax.nn.softmax(logits, axis=-1)             # (G, T, E)
+        capacity = max(1, int(math.ceil(tg * m.top_k / m.num_experts
+                                        * m.capacity_factor)))
+        dispatch, combine, aux = topk_dispatch(gates, m.top_k, capacity,
+                                               x.dtype)
+        ein = jnp.einsum("gtd,gtec->gecd", xg, dispatch)    # (G, E, C, d)
+    with jax.named_scope("moe_experts"):
+        h = L._act(cfg.act, jnp.einsum("gecd,edf->gecf", ein, p["w_gate"]))
+        h = h * jnp.einsum("gecd,edf->gecf", ein, p["w_up"])
+        eout = jnp.einsum("gecf,efd->gecd", h, p["w_down"])  # (G, E, C, d)
+    with jax.named_scope("moe_dispatch"):
+        out = jnp.einsum("gecd,gtec->gtd", eout, combine)
     out = out.reshape(b, s, d)
     if m.num_shared_experts > 0:
         out = out + L.mlp(p["shared"], x, cfg.act)

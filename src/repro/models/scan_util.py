@@ -20,6 +20,7 @@ def unrolling() -> bool:
     return os.environ.get("REPRO_UNROLL_SCAN", "0") == "1"
 
 
+@jax.named_scope("layer_scan")
 def scan(f: Callable, init: Any, xs: Any) -> Tuple[Any, Any]:
     """Drop-in for ``jax.lax.scan(f, init, xs)`` honouring the unroll flag."""
     if not unrolling():

@@ -49,6 +49,7 @@ def conv1d_decode(x_new: jnp.ndarray, state: jnp.ndarray, w: jnp.ndarray,
 # chunked SSD
 # ---------------------------------------------------------------------------
 
+@jax.named_scope("ssd_scan")
 def ssd_chunked(xb: jnp.ndarray, a: jnp.ndarray, bmat: jnp.ndarray,
                 cmat: jnp.ndarray, chunk: int,
                 init_state: Optional[jnp.ndarray] = None,

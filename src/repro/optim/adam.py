@@ -61,6 +61,7 @@ def lr_schedule(cfg: OptimizerConfig, step: jnp.ndarray) -> jnp.ndarray:
     return cfg.lr * warm * decay
 
 
+@jax.named_scope("adam")
 def adam_update(cfg: OptimizerConfig, params: Params, grads: Params,
                 state: OptState, lr_scale: jnp.ndarray | float = 1.0,
                 *, grad_norm: Optional[jnp.ndarray] = None,

@@ -43,7 +43,6 @@ import jax.numpy as jnp
 from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro import telemetry
 from repro.config import ModelConfig, OptimizerConfig
 from repro.core.stages import StagePartition
 from repro.core.swap import stage_permutations
@@ -381,17 +380,7 @@ def make_spmd_fused_train_step(model, opt_cfg: OptimizerConfig,
             check_vma=False)
         return f(params, opt_state, stacked, lr_scale, n)
 
-    # host-side dispatch span (repro.telemetry): times the enqueue of the
-    # sharded window, never runs inside traced code.  ``functools.wraps``
-    # carries the ``_jitted`` attribute across, which the retrace sentinel
-    # (repro.analysis.runtime.compiled_variant_count) introspects.
-    @functools.wraps(fused_step)
-    def dispatch(*args):
-        with telemetry.span("spmd_window_dispatch", cat="pipeline",
-                            stages=K):
-            return fused_step(*args)
-
-    return dispatch
+    return fused_step
 
 
 # ---------------------------------------------------------------------------

@@ -127,6 +127,23 @@ class RecoveryStrategy:
         return self
 
     # ---- instrumented entry points (what the trainer calls) ----------
+    def _recorded(self, recover: Callable[[], "TrainState"],
+                  event: FailureContext, recovered: List[int],
+                  **span_args) -> "TrainState":
+        """Run ``recover()`` inside a host-side ``recovery`` span (the
+        parent of the strategy's own phase spans) and emit the structured
+        ``recovery`` event (``repro.telemetry``)."""
+        t0 = telemetry.clock()
+        with telemetry.span("recovery", cat="recovery", strategy=self.name,
+                            stage=event.stage, wall_step=event.wall_step,
+                            **span_args):
+            state = recover()
+            duration = telemetry.clock() - t0
+        telemetry.emit("recovery", wall_step=event.wall_step,
+                       stage=event.stage, strategy=self.name,
+                       duration_s=duration, stages=recovered)
+        return state
+
     def handle_failure(self, state: "TrainState",
                        event: FailureContext) -> "TrainState":
         """:meth:`on_failure` wrapped in a host-side trace span and a
@@ -134,31 +151,16 @@ class RecoveryStrategy:
         routes failures through here so every policy's recovery execution
         is measured uniformly; subclasses keep overriding
         :meth:`on_failure` and never need to touch this."""
-        t0 = telemetry.clock()
-        state = self.on_failure(state, event)
-        duration = telemetry.clock() - t0
-        telemetry.complete("recovery", t0, cat="recovery",
-                           strategy=self.name, stage=event.stage)
-        telemetry.emit("recovery", wall_step=event.wall_step,
-                       stage=event.stage, strategy=self.name,
-                       duration_s=duration, stages=[event.stage])
-        return state
+        return self._recorded(lambda: self.on_failure(state, event), event,
+                              [event.stage])
 
     def handle_consecutive(self, state: "TrainState", run: List[int],
                            event: FailureContext) -> "TrainState":
         """:meth:`on_consecutive` with the same span + event treatment as
         :meth:`handle_failure` (one ``recovery`` event for the whole
         adjacent-stage run)."""
-        t0 = telemetry.clock()
-        state = self.on_consecutive(state, run, event)
-        duration = telemetry.clock() - t0
-        telemetry.complete("recovery", t0, cat="recovery",
-                           strategy=self.name, stage=event.stage,
-                           stages=len(run))
-        telemetry.emit("recovery", wall_step=event.wall_step,
-                       stage=event.stage, strategy=self.name,
-                       duration_s=duration, stages=list(run))
-        return state
+        return self._recorded(lambda: self.on_consecutive(state, run, event),
+                              event, list(run), stages=len(run))
 
     def handle_departure(self, state: "TrainState",
                          event: FailureContext) -> "TrainState":
@@ -167,15 +169,8 @@ class RecoveryStrategy:
         permanent departure the trainer will repartition away — the
         strategy's job here is only to reconstruct the lost stage's values
         in the *old* layout; the trainer re-cuts the layout afterwards."""
-        t0 = telemetry.clock()
-        state = self.on_departure(state, event)
-        duration = telemetry.clock() - t0
-        telemetry.complete("recovery", t0, cat="recovery",
-                           strategy=self.name, stage=event.stage)
-        telemetry.emit("recovery", wall_step=event.wall_step,
-                       stage=event.stage, strategy=self.name,
-                       duration_s=duration, stages=[event.stage])
-        return state
+        return self._recorded(lambda: self.on_departure(state, event), event,
+                              [event.stage])
 
     # ---- lifecycle ---------------------------------------------------
     def on_failure(self, state: "TrainState",

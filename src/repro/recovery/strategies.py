@@ -27,6 +27,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import telemetry
 from repro.core.recovery import (recover_consecutive, recover_stage,
                                  recovery_error)
 from repro.pipeline.spmd import IN_MESH_REINITS
@@ -130,6 +131,13 @@ class Checkpointing(RecoveryStrategy):
                 + remote.read_time_s(self.wall.model_bytes))
 
 
+def _phase(name: str, event: FailureContext):
+    """Host span of one phase of a merge recovery: the eager merge's
+    dispatch, the drain of its recovery error (which waits for the merge
+    on the device), and the dispatch of the moment reset."""
+    return telemetry.span(name, cat="recovery", wall_step=event.wall_step)
+
+
 class MergeRecovery(RecoveryStrategy):
     """Shared CheckFree-family machinery: neighbour-merge reinit of the failed
     stage, zeroed optimizer moments for that stage, Alg. 1's LR boost.
@@ -173,19 +181,24 @@ class MergeRecovery(RecoveryStrategy):
             # protects them; if an event still arrives, degrade to copy.
             reinit = "copy_prev"
         before = state.params
-        if self._in_mesh_recover is not None and reinit in IN_MESH_REINITS:
-            params = self._in_mesh_recover(before, self._omegas(state),
-                                           event.stage, reinit)
-        else:
-            params = recover_stage(before, self.part, event.stage,
-                                   self._omegas(state), strategy=reinit,
-                                   key=event.key)
+        with _phase("recovery_merge", event):
+            if self._in_mesh_recover is not None and \
+                    reinit in IN_MESH_REINITS:
+                params = self._in_mesh_recover(before, self._omegas(state),
+                                               event.stage, reinit)
+            else:
+                params = recover_stage(before, self.part, event.stage,
+                                       self._omegas(state), strategy=reinit,
+                                       key=event.key)
         # explicit drain: the recovery error is a host-side metric, and the
         # failure path must stay legal under the implicit-transfer guard
-        err = float(jax.device_get(
-            recovery_error(before, params, self.part, event.stage)))
+        with _phase("recovery_error_drain", event):
+            err = float(jax.device_get(
+                recovery_error(before, params, self.part, event.stage)))
         event.hist.recovery_errors.append((event.wall_step, err))
-        opt_state = self._zero_stage_moments(state.opt_state, [event.stage])
+        with _phase("recovery_moment_reset", event):
+            opt_state = self._zero_stage_moments(state.opt_state,
+                                                 [event.stage])
         return TrainState(params, opt_state, self._boosted(state.lr_scale),
                           state.omegas, state.effective_step)
 
@@ -194,13 +207,17 @@ class MergeRecovery(RecoveryStrategy):
         """Beyond-paper: a run of consecutive stages died together —
         distance-weighted interpolation between the surviving flanks."""
         before = state.params
-        params = recover_consecutive(before, self.part, run,
-                                     self._omegas(state))
-        for stage in run:
-            err = float(jax.device_get(
+        with _phase("recovery_merge", event):
+            params = recover_consecutive(before, self.part, run,
+                                         self._omegas(state))
+        with _phase("recovery_error_drain", event):
+            errs = [float(jax.device_get(
                 recovery_error(before, params, self.part, stage)))
-            event.hist.recovery_errors.append((event.wall_step, err))
-        opt_state = self._zero_stage_moments(state.opt_state, run)
+                for stage in run]
+        event.hist.recovery_errors.extend(
+            (event.wall_step, err) for err in errs)
+        with _phase("recovery_moment_reset", event):
+            opt_state = self._zero_stage_moments(state.opt_state, run)
         return TrainState(params, opt_state, self._boosted(state.lr_scale),
                           state.omegas, state.effective_step)
 

@@ -7,14 +7,16 @@ below is a cheap no-op until :func:`configure` installs one) collects:
 * **structured events** — schema-versioned JSONL records for step
   windows, failures, recoveries, snapshot saves/restores, simulated node
   churn, truncation (:mod:`repro.telemetry.events`);
-* **counters / gauges / histograms** — :func:`inc` / :func:`gauge` /
-  :func:`observe`;
+* **counters** — :func:`inc`;
 * **trace spans** — host-side timings around the hot-path boundaries
-  (window dispatch/drain, SPMD dispatch, snapshot writes, restores,
-  recovery execution), exported as Chrome ``trace_event`` JSON for
-  Perfetto (:mod:`repro.telemetry.trace`);
+  (window dispatch/drain, the window boundary's bookkeeping, failures and
+  their recovery phases, snapshot writes, restores), each with its parent
+  span, exported as Chrome ``trace_event`` JSON for Perfetto
+  (:mod:`repro.telemetry.trace`);
+* **anchors** — :func:`anchor` writes the recorder's clock into the JAX
+  profiler's trace once per window, so spans map onto device time;
 * **derived run metrics** — goodput, per-strategy recovery breakdown,
-  per-tier snapshot bytes, straggler stretch, MFU
+  per-tier snapshot bytes, straggler stretch
   (:mod:`repro.telemetry.metrics`), rendered by
   ``python -m repro.telemetry.report`` (:mod:`repro.telemetry.report`).
 
@@ -26,16 +28,15 @@ from repro.telemetry.events import (EVENT_KINDS, SCHEMA_VERSION,
                                     validate_events, validate_record)
 from repro.telemetry.log import log, set_verbosity, verbosity
 from repro.telemetry.metrics import compute_metrics, render_text
-from repro.telemetry.recorder import (Recorder, clock, complete, configure,
-                                      emit, enabled, gauge, get_recorder,
-                                      inc, observe, set_recorder, span,
-                                      traced)
+from repro.telemetry.recorder import (Recorder, anchor, clock, complete,
+                                      configure, emit, enabled, get_recorder,
+                                      inc, set_recorder, span)
 from repro.telemetry.trace import chrome_trace, load_chrome_trace
 
 __all__ = [
-    "EVENT_KINDS", "SCHEMA_VERSION", "Recorder",
+    "EVENT_KINDS", "SCHEMA_VERSION", "Recorder", "anchor",
     "chrome_trace", "clock", "complete", "compute_metrics", "configure",
-    "emit", "enabled", "gauge", "get_recorder", "inc", "load_chrome_trace",
-    "log", "observe", "render_text", "set_recorder", "set_verbosity",
-    "span", "traced", "validate_events", "validate_record", "verbosity",
+    "emit", "enabled", "get_recorder", "inc", "load_chrome_trace",
+    "log", "render_text", "set_recorder", "set_verbosity",
+    "span", "validate_events", "validate_record", "verbosity",
 ]

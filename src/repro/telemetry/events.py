@@ -17,7 +17,8 @@ from __future__ import annotations
 
 from typing import Any, Dict, Iterable, List, Tuple
 
-SCHEMA_VERSION = 2   # v2 adds: repartition, tier_retry
+SCHEMA_VERSION = 3   # v2 adds: repartition, tier_retry
+#                      v3 drops run_start.flops_per_step
 
 _NUM = (int, float)
 _INT = (int,)
@@ -29,8 +30,7 @@ EVENT_FIELDS: Dict[str, Dict[str, Tuple[type, ...]]] = {
     # run lifecycle -------------------------------------------------------
     "run_start": {
         "arch": _STR, "strategy": _STR, "backend": _STR,
-        "steps": _INT, "num_stages": _INT,
-        "flops_per_step": _NUM, "tokens_per_step": _NUM,
+        "steps": _INT, "num_stages": _INT, "tokens_per_step": _NUM,
     },
     "run_end": {
         "effective_steps": _INT, "wall_iters": _INT, "dispatches": _INT,

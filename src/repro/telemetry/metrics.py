@@ -13,8 +13,6 @@ schema) to a JSON-able metrics object:
   time per state-store tier (the TierCheck axis).
 * **straggler stretch** — mean / max iteration-time multiplier actually
   paid (the simulator's slowest-participant pricing).
-* **MFU estimate** — per-family FLOPs (``6 * active_params * tokens`` for
-  training) over measured host time, against a peak-FLOPs reference.
 
 Everything here is stdlib-only so the report CLI works on machines
 without jax installed.
@@ -33,20 +31,14 @@ def _by_kind(events: Iterable[dict]) -> Dict[str, List[dict]]:
     return out
 
 
-def compute_metrics(events: List[dict], *,
-                    peak_flops: Optional[float] = None) -> Dict[str, Any]:
-    """Derive the run-level metrics object from an event stream.
-
-    ``peak_flops`` (FLOP/s) turns the achieved-FLOPs rate into an MFU
-    fraction; without it only the achieved rate is reported.
-    """
+def compute_metrics(events: List[dict]) -> Dict[str, Any]:
+    """Derive the run-level metrics object from an event stream."""
     by = _by_kind(events)
     out: Dict[str, Any] = {
         "schema_version": SCHEMA_VERSION,
         "counts": {k: len(v) for k, v in sorted(by.items())},
     }
 
-    start = by.get("run_start", [None])[0]
     end = by.get("run_end", [None])[-1]
 
     # ---- goodput ------------------------------------------------------
@@ -135,22 +127,6 @@ def compute_metrics(events: List[dict], *,
     for e in by.get("sim_node", ()):
         churn[e.get("what", "?")] = churn.get(e.get("what", "?"), 0) + 1
     out["node_churn"] = churn
-
-    # ---- MFU ----------------------------------------------------------
-    mfu: Dict[str, Any] = {"flops_per_step": None,
-                           "achieved_flops_per_s": None, "mfu": None}
-    if start is not None and end is not None:
-        fps = float(start.get("flops_per_step", 0.0))
-        elapsed = float(end.get("t_s", 0.0)) - float(start.get("t_s", 0.0))
-        mfu["flops_per_step"] = fps
-        mfu["measured_wall_s"] = elapsed
-        if fps > 0 and elapsed > 0:
-            achieved = fps * end.get("effective_steps", 0) / elapsed
-            mfu["achieved_flops_per_s"] = achieved
-            if peak_flops:
-                mfu["mfu"] = achieved / peak_flops
-                mfu["peak_flops"] = peak_flops
-    out["mfu"] = mfu
     return out
 
 
@@ -231,13 +207,6 @@ def render_text(metrics: Dict[str, Any]) -> str:
     if churn:
         lines.append("node churn        : " + ", ".join(
             f"{k}={v}" for k, v in sorted(churn.items())))
-    mfu = metrics.get("mfu") or {}
-    if mfu.get("achieved_flops_per_s"):
-        lines.append(f"achieved FLOP/s   : "
-                     f"{mfu['achieved_flops_per_s']:.3e}")
-        if mfu.get("mfu") is not None:
-            lines.append(f"MFU               : {mfu['mfu']:.2%} of "
-                         f"{mfu['peak_flops']:.2e} FLOP/s peak")
     counts = metrics.get("counts") or {}
     lines.append("events            : " + ", ".join(
         f"{k}={v}" for k, v in sorted(counts.items())))

@@ -1,9 +1,10 @@
 """The process-wide telemetry recorder.
 
-One :class:`Recorder` owns everything a run produces: counters / gauges /
-histogram summaries, the structured JSONL event stream
-(:mod:`repro.telemetry.events`), and host-side trace *spans* exported as
-Chrome ``trace_event`` JSON (:mod:`repro.telemetry.trace`).  Installation
+One :class:`Recorder` owns everything a run produces: counters, the
+structured JSONL event stream (:mod:`repro.telemetry.events`), host-side
+trace *spans* exported as Chrome ``trace_event`` JSON
+(:mod:`repro.telemetry.trace`), and the *anchors* that tie the recorder's
+clock to the JAX profiler's (:func:`anchor`).  Installation
 is process-global (``configure()`` / ``set_recorder()``) so deeply nested
 layers — the fused-window trainer loop, the async snapshot writer thread,
 the cluster simulator — all reach the same sink through the module-level
@@ -17,6 +18,12 @@ no allocation, no lock, no clock read.  The trainer's fused window must
 stay within 2% of its telemetry-free throughput (see
 ``docs/observability.md``), which is why nothing here may run work on the
 disabled path.
+
+**Span nesting.**  Each span records its ``parent``: the innermost span
+open on the same thread when it ends (``None`` at the top level).  Only
+:func:`span` opens a span; one recorded through :func:`clock` /
+:func:`complete` is never a parent, so a region that may hold child spans
+uses :func:`span`.
 
 **Host-side only.**  Spans and events record *around* dispatch/drain
 boundaries, never inside traced code, and event payloads must already be
@@ -33,7 +40,6 @@ on their own track.
 from __future__ import annotations
 
 import contextlib
-import functools
 import io
 import json
 import numbers
@@ -46,6 +52,8 @@ from repro.telemetry.events import SCHEMA_VERSION
 
 EVENTS_FILENAME = "events.jsonl"
 TRACE_FILENAME = "trace.json"
+#: name of the profiler annotation :meth:`Recorder.anchor` writes
+ANCHOR = "repro.anchor"
 
 
 def _jsonable(v: Any) -> Any:
@@ -74,33 +82,8 @@ def _jsonable(v: Any) -> Any:
     return str(v)
 
 
-class _HistSummary:
-    """Streaming histogram summary: count / sum / min / max (no samples
-    are retained — the event stream is the raw record)."""
-
-    __slots__ = ("count", "total", "min", "max")
-
-    def __init__(self) -> None:
-        self.count = 0
-        self.total = 0.0
-        self.min = float("inf")
-        self.max = float("-inf")
-
-    def add(self, value: float) -> None:
-        self.count += 1
-        self.total += value
-        self.min = min(self.min, value)
-        self.max = max(self.max, value)
-
-    def summary(self) -> Dict[str, float]:
-        return {"count": self.count, "sum": self.total,
-                "min": self.min if self.count else 0.0,
-                "max": self.max if self.count else 0.0,
-                "mean": self.total / self.count if self.count else 0.0}
-
-
 class Recorder:
-    """Counters, gauges, histograms, events, and trace spans for one run."""
+    """Counters, events, trace spans and profiler anchors for one run."""
 
     def __init__(self, run_dir: Optional[str] = None, *,
                  stream: bool = True,
@@ -110,10 +93,12 @@ class Recorder:
         self._t0 = clock()
         self._lock = threading.Lock()
         self.counters: Dict[str, float] = {}
-        self.gauges: Dict[str, float] = {}
-        self.hists: Dict[str, _HistSummary] = {}
         self.events: List[dict] = []
         self.spans: List[dict] = []
+        #: recorder seconds (:meth:`now`) of each anchor written into the
+        #: profiler's trace
+        self.anchors: List[float] = []
+        self._open = threading.local()      # names of the open spans
         self._file: Optional[io.TextIOBase] = None
         if run_dir is not None and stream:
             os.makedirs(run_dir, exist_ok=True)
@@ -128,14 +113,6 @@ class Recorder:
     def inc(self, name: str, n: float = 1) -> None:
         with self._lock:
             self.counters[name] = self.counters.get(name, 0) + n
-
-    def gauge(self, name: str, value: float) -> None:
-        with self._lock:
-            self.gauges[name] = float(value)
-
-    def observe(self, name: str, value: float) -> None:
-        with self._lock:
-            self.hists.setdefault(name, _HistSummary()).add(float(value))
 
     # ---- events -------------------------------------------------------
     def event(self, kind: str, **fields: Any) -> dict:
@@ -152,38 +129,59 @@ class Recorder:
         return rec
 
     # ---- spans --------------------------------------------------------
+    def _stack(self) -> List[str]:
+        stack = getattr(self._open, "names", None)
+        if stack is None:
+            stack = self._open.names = []
+        return stack
+
     def complete(self, name: str, t0: float, *, cat: str = "repro",
                  **args: Any) -> None:
         """Record a finished span that started at host time ``t0``
-        (a value previously obtained from :func:`clock`)."""
+        (a value previously obtained from :func:`clock`); its parent is
+        the innermost :meth:`span` open on this thread."""
         t1 = self._clock()
+        stack = self._stack()
         with self._lock:
             self.spans.append({
                 "name": name, "cat": cat,
                 "ts_us": (t0 - self._t0) * 1e6,
                 "dur_us": (t1 - t0) * 1e6,
                 "tid": threading.get_ident(),
+                "parent": stack[-1] if stack else None,
                 "args": {k: _jsonable(v) for k, v in args.items()},
             })
 
     @contextlib.contextmanager
     def span(self, name: str, *, cat: str = "repro", **args: Any):
+        stack = self._stack()
         t0 = self._clock()
+        stack.append(name)
         try:
             yield
         finally:
+            stack.pop()
             self.complete(name, t0, cat=cat, **args)
+
+    # ---- anchors ------------------------------------------------------
+    def anchor(self) -> None:
+        """Write the recorder's clock into the JAX profiler's trace: a
+        zero-length ``TraceAnnotation`` named :data:`ANCHOR` whose
+        ``t_s`` argument is :meth:`now`, so a reader of the trace can map
+        every span onto the profiler's clock through the nearest anchor.
+        Outside a profiler session the annotation records nothing."""
+        from jax.profiler import TraceAnnotation   # the report runs jax-free
+        t = self.now()
+        with TraceAnnotation(ANCHOR, t_s=t):
+            pass
+        with self._lock:
+            self.anchors.append(t)
 
     # ---- export -------------------------------------------------------
     def snapshot(self) -> Dict[str, Any]:
-        """Point-in-time metric values (JSON-able)."""
+        """Point-in-time counter values (JSON-able)."""
         with self._lock:
-            return {
-                "counters": dict(self.counters),
-                "gauges": dict(self.gauges),
-                "histograms": {k: h.summary()
-                               for k, h in self.hists.items()},
-            }
+            return {"counters": dict(self.counters)}
 
     def chrome_trace(self) -> Dict[str, Any]:
         from repro.telemetry.trace import chrome_trace
@@ -257,18 +255,6 @@ def inc(name: str, n: float = 1) -> None:
         r.inc(name, n)
 
 
-def gauge(name: str, value: float) -> None:
-    r = _RECORDER
-    if r is not None:
-        r.gauge(name, value)
-
-
-def observe(name: str, value: float) -> None:
-    r = _RECORDER
-    if r is not None:
-        r.observe(name, value)
-
-
 def span(name: str, *, cat: str = "repro", **args: Any):
     """Context manager timing a host-side region (no-op when disabled)."""
     r = _RECORDER
@@ -302,15 +288,9 @@ def complete(name: str, t0: float, *, cat: str = "repro",
         r.complete(name, t0, cat=cat, **args)
 
 
-def traced(name: str, *, cat: str = "repro"):
-    """Decorator form of :func:`span` for whole-function spans."""
-    def deco(fn):
-        @functools.wraps(fn)
-        def wrapper(*a, **kw):
-            r = _RECORDER
-            if r is None:
-                return fn(*a, **kw)
-            with r.span(name, cat=cat):
-                return fn(*a, **kw)
-        return wrapper
-    return deco
+def anchor() -> None:
+    """Write an anchor into the profiler's trace (:meth:`Recorder.anchor`);
+    a no-op when disabled."""
+    r = _RECORDER
+    if r is not None:
+        r.anchor()

@@ -1,13 +1,13 @@
 """Run-directory report CLI.
 
     PYTHONPATH=src python -m repro.telemetry.report RUN_DIR \
-        [--json] [--strict] [--peak-flops 197e12]
+        [--json] [--strict]
 
 ``RUN_DIR`` is a ``--telemetry-dir`` produced by ``repro.launch.train``
 (or any directory holding an ``events.jsonl``); a path to the JSONL file
 itself also works.  The report validates every record against the event
 schema, derives the run-level metrics (goodput, per-strategy recovery
-breakdown, per-tier snapshot volume, straggler stretch, MFU — see
+breakdown, per-tier snapshot volume, straggler stretch — see
 :mod:`repro.telemetry.metrics`), and renders them as text or JSON.
 
 ``--strict`` is the CI contract: exit 2 on schema violations, exit 1 when
@@ -60,9 +60,6 @@ def main(argv: Optional[List[str]] = None) -> int:
     ap.add_argument("--strict", action="store_true",
                     help="exit non-zero on schema violations or missing "
                          "required metrics (the CI contract)")
-    ap.add_argument("--peak-flops", type=float, default=0.0,
-                    help="peak FLOP/s reference for the MFU estimate "
-                         "(e.g. 197e12; 0 skips MFU)")
     args = ap.parse_args(argv)
 
     try:
@@ -82,8 +79,7 @@ def main(argv: Optional[List[str]] = None) -> int:
         if args.strict:
             return 2
 
-    metrics = compute_metrics(events,
-                              peak_flops=args.peak_flops or None)
+    metrics = compute_metrics(events)
     if args.json:
         print(json.dumps(metrics, indent=1))   # repro: allow[no-bare-print]
     else:

@@ -1,6 +1,7 @@
 """Chrome ``trace_event`` export.
 
-The recorder's spans become ``"ph": "X"`` (complete) events and the
+The recorder's spans become ``"ph": "X"`` (complete) events, each with
+its parent span's name under ``args.parent`` where it has one, and the
 structured event stream becomes ``"ph": "i"`` (instant) markers, all in
 one process track with per-thread rows — the JSON loads directly in
 Perfetto / ``chrome://tracing``.  Timestamps are microseconds since the
@@ -36,12 +37,15 @@ def chrome_trace(spans: Iterable[dict],
         return tid_map[tid]
 
     for s in spans:
+        args = dict(s.get("args", {}))
+        if s.get("parent") is not None:
+            args["parent"] = s["parent"]
         out.append({
             "name": s["name"], "cat": s.get("cat", "repro"), "ph": "X",
             "ts": round(float(s["ts_us"]), 3),
             "dur": round(float(s["dur_us"]), 3),
             "pid": PID, "tid": row(int(s.get("tid", 0))),
-            "args": s.get("args", {}),
+            "args": args,
         })
     for e in events:
         out.append({
