@@ -165,6 +165,16 @@ def recover_consecutive(params: Params, part: StagePartition,
     return out
 
 
+def zero_stages(tree: Params, part: StagePartition,
+                stages: "list[int]") -> Params:
+    """``tree`` with every leaf of the lost ``stages`` set to zero: the
+    failed node's Adam moments are gone with it."""
+    for stage in stages:
+        zeros = jax.tree.map(jnp.zeros_like, part.get_stage(tree, stage))
+        tree = part.set_stage(tree, stage, zeros)
+    return tree
+
+
 def recovery_error(params_before: Params, params_after: Params,
                    part: StagePartition, failed: int) -> jnp.ndarray:
     """||omega1 f_{k+1} + omega2 f_{k-1} - f_k||^2 — the per-failure error term

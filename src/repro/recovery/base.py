@@ -77,6 +77,9 @@ class FailureContext:
     wall_step: int             # wall-iteration index of the event
     key: jax.Array             # PRNG key (random reinit ablation)
     hist: "History"            # strategies append recovery_errors here
+    path: Optional[str] = None  # set by the strategy: how it recovered,
+                                # "program" (one compiled recovery) or
+                                # "in_mesh" (the SPMD collective)
 
 
 class RecoveryStrategy:
@@ -132,7 +135,8 @@ class RecoveryStrategy:
                   **span_args) -> "TrainState":
         """Run ``recover()`` inside a host-side ``recovery`` span (the
         parent of the strategy's own phase spans) and emit the structured
-        ``recovery`` event (``repro.telemetry``)."""
+        ``recovery`` event (``repro.telemetry``), with the ``path`` the
+        strategy took (None where it names none)."""
         t0 = telemetry.clock()
         with telemetry.span("recovery", cat="recovery", strategy=self.name,
                             stage=event.stage, wall_step=event.wall_step,
@@ -141,7 +145,8 @@ class RecoveryStrategy:
             duration = telemetry.clock() - t0
         telemetry.emit("recovery", wall_step=event.wall_step,
                        stage=event.stage, strategy=self.name,
-                       duration_s=duration, stages=recovered)
+                       duration_s=duration, stages=recovered,
+                       path=event.path)
         return state
 
     def handle_failure(self, state: "TrainState",

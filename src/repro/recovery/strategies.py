@@ -21,7 +21,8 @@ these classes bind it to the trainer lifecycle and the wall-clock model.
 """
 from __future__ import annotations
 
-from typing import ClassVar, List
+import functools
+from typing import Callable, ClassVar, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -29,7 +30,7 @@ import numpy as np
 
 from repro import telemetry
 from repro.core.recovery import (recover_consecutive, recover_stage,
-                                 recovery_error)
+                                 recovery_error, zero_stages)
 from repro.pipeline.spmd import IN_MESH_REINITS
 from repro.core.state import History, TrainState
 from repro.optim.adam import OptState
@@ -132,45 +133,113 @@ class Checkpointing(RecoveryStrategy):
 
 
 def _phase(name: str, event: FailureContext):
-    """Host span of one phase of a merge recovery: the eager merge's
-    dispatch, the drain of its recovery error (which waits for the merge
-    on the device), and the dispatch of the moment reset."""
+    """Host span of one phase of a merge recovery: the dispatch of the
+    recovery, and the drain of its recovery error (which waits for the
+    whole recovery on the device)."""
     return telemetry.span(name, cat="recovery", wall_step=event.wall_step)
+
+
+def _recovery_program(part, stages: Tuple[int, ...], reinit: Optional[str],
+                      tower, m, v, omegas, key):
+    """One failure's recovery, traced as one program: the lost ``stages``
+    rebuilt (``recover_stage`` for one stage, ``recover_consecutive`` for
+    a run, where ``reinit`` is None), each one's recovery error, and their
+    Adam moments zeroed.  It reads and returns the tower subtrees of the
+    params and both moments only; the embeddings and the step count stay
+    where they are.  Nothing is donated: the caller still holds the
+    pre-failure state, and the new towers are fresh buffers."""
+    tk = part.tower_key
+    before = {tk: tower}
+    if reinit is None:
+        after = recover_consecutive(before, part, list(stages), omegas)
+    else:
+        (stage,) = stages
+        after = recover_stage(before, part, stage, omegas, strategy=reinit,
+                              key=key)
+    errs = jnp.stack([recovery_error(before, after, part, s)
+                      for s in stages])
+    m = zero_stages({tk: m}, part, stages)[tk]
+    v = zero_stages({tk: v}, part, stages)[tk]
+    return after[tk], m, v, errs
 
 
 class MergeRecovery(RecoveryStrategy):
     """Shared CheckFree-family machinery: neighbour-merge reinit of the failed
     stage, zeroed optimizer moments for that stage, Alg. 1's LR boost.
 
+    A failure (or a consecutive run) is one compiled program
+    (:func:`_recovery_program`), built at the first failure of each
+    (layout, stages, reinit) and reused after; a re-layout builds its own.
+
     On the SPMD backend the trainer binds an in-mesh collective
     (``bind_in_mesh``); deterministic reinits then run as neighbour-hop
     ppermutes + a local merge on the stage-sharded tower instead of
     host-side slice gathers.  Stochastic reinits (``random``) and
-    consecutive-run recovery keep the host path — they are rare events and
+    consecutive-run recovery keep the program — they are rare events and
     bit-match either way."""
 
     recover_in_mesh = True
     reinit: ClassVar[str] = "grad_norm"
 
-    def _omegas(self, state: TrainState) -> jnp.ndarray:
+    def __init__(self, rcfg, wall):
+        super().__init__(rcfg, wall)
+        # (layer_counts, stages, reinit) -> the jitted recovery program
+        self._programs: Dict[Tuple, Callable] = {}
+
+    def _omegas(self, state: TrainState) -> jax.Array:
         k = self.part.num_stages
-        return jnp.asarray(state.omegas if state.omegas is not None
-                           else np.ones((k,), np.float32))
+        return jax.device_put(state.omegas if state.omegas is not None
+                              else np.ones((k,), np.float32))
 
     def _boosted(self, lr_scale: float) -> float:
         return min(lr_scale * self.rcfg.lr_boost,
                    self.rcfg.lr_boost_cap)  # Alg. 1 line 4 (capped)
 
-    def _zero_stage_moments(self, opt_state: OptState,
-                            stages: List[int]) -> OptState:
-        # the failed node's optimizer moments are gone: zero those stages
-        m, v = opt_state.m, opt_state.v
-        for stage in stages:
-            zeros = jax.tree.map(jnp.zeros_like,
-                                 self.part.get_stage(m, stage))
-            m = self.part.set_stage(m, stage, zeros)
-            v = self.part.set_stage(v, stage, zeros)
-        return OptState(m, v, opt_state.step)
+    def _program(self, stages: Tuple[int, ...],
+                 reinit: Optional[str]) -> Callable:
+        key = (self.part.layer_counts, stages, reinit)
+        if key not in self._programs:
+            self._programs[key] = jax.jit(functools.partial(
+                _recovery_program, self.part, stages, reinit))
+        return self._programs[key]
+
+    def _recover(self, state: TrainState, stages: Tuple[int, ...],
+                 reinit: Optional[str], event: FailureContext) -> TrainState:
+        tk = self.part.tower_key
+        params, opt = state.params, state.opt_state
+        with _phase("recovery_merge", event):
+            tower, m, v, errs = self._program(stages, reinit)(
+                params[tk], opt.m[tk], opt.v[tk], self._omegas(state),
+                event.key if reinit == "random" else None)
+        # explicit drain: the recovery error is a host-side metric, and the
+        # failure path must stay legal under the implicit-transfer guard
+        with _phase("recovery_error_drain", event):
+            errs = jax.device_get(errs)
+        event.hist.recovery_errors.extend(
+            (event.wall_step, float(err)) for err in errs)
+        event.path = "program"
+        return TrainState({**params, tk: tower},
+                          OptState({**opt.m, tk: m}, {**opt.v, tk: v},
+                                   opt.step),
+                          self._boosted(state.lr_scale),
+                          state.omegas, state.effective_step)
+
+    def _recover_in_mesh(self, state: TrainState, reinit: str,
+                         event: FailureContext) -> TrainState:
+        before, opt = state.params, state.opt_state
+        stages = [event.stage]
+        with _phase("recovery_merge", event):
+            params = self._in_mesh_recover(before, self._omegas(state),
+                                           event.stage, reinit)
+            opt = OptState(zero_stages(opt.m, self.part, stages),
+                           zero_stages(opt.v, self.part, stages), opt.step)
+        with _phase("recovery_error_drain", event):
+            err = float(jax.device_get(
+                recovery_error(before, params, self.part, event.stage)))
+        event.hist.recovery_errors.append((event.wall_step, err))
+        event.path = "in_mesh"
+        return TrainState(params, opt, self._boosted(state.lr_scale),
+                          state.omegas, state.effective_step)
 
     def on_failure(self, state: TrainState,
                    event: FailureContext) -> TrainState:
@@ -180,46 +249,15 @@ class MergeRecovery(RecoveryStrategy):
             # CheckFree (no '+') cannot recover edge stages — the paper
             # protects them; if an event still arrives, degrade to copy.
             reinit = "copy_prev"
-        before = state.params
-        with _phase("recovery_merge", event):
-            if self._in_mesh_recover is not None and \
-                    reinit in IN_MESH_REINITS:
-                params = self._in_mesh_recover(before, self._omegas(state),
-                                               event.stage, reinit)
-            else:
-                params = recover_stage(before, self.part, event.stage,
-                                       self._omegas(state), strategy=reinit,
-                                       key=event.key)
-        # explicit drain: the recovery error is a host-side metric, and the
-        # failure path must stay legal under the implicit-transfer guard
-        with _phase("recovery_error_drain", event):
-            err = float(jax.device_get(
-                recovery_error(before, params, self.part, event.stage)))
-        event.hist.recovery_errors.append((event.wall_step, err))
-        with _phase("recovery_moment_reset", event):
-            opt_state = self._zero_stage_moments(state.opt_state,
-                                                 [event.stage])
-        return TrainState(params, opt_state, self._boosted(state.lr_scale),
-                          state.omegas, state.effective_step)
+        if self._in_mesh_recover is not None and reinit in IN_MESH_REINITS:
+            return self._recover_in_mesh(state, reinit, event)
+        return self._recover(state, (event.stage,), reinit, event)
 
     def on_consecutive(self, state: TrainState, run: List[int],
                        event: FailureContext) -> TrainState:
         """Beyond-paper: a run of consecutive stages died together —
         distance-weighted interpolation between the surviving flanks."""
-        before = state.params
-        with _phase("recovery_merge", event):
-            params = recover_consecutive(before, self.part, run,
-                                         self._omegas(state))
-        with _phase("recovery_error_drain", event):
-            errs = [float(jax.device_get(
-                recovery_error(before, params, self.part, stage)))
-                for stage in run]
-        event.hist.recovery_errors.extend(
-            (event.wall_step, err) for err in errs)
-        with _phase("recovery_moment_reset", event):
-            opt_state = self._zero_stage_moments(state.opt_state, run)
-        return TrainState(params, opt_state, self._boosted(state.lr_scale),
-                          state.omegas, state.effective_step)
+        return self._recover(state, tuple(run), None, event)
 
     def failure_cost(self) -> float:
         return self.wall.recovery_time_s

@@ -445,6 +445,7 @@ def test_trainer_emits_schema_valid_stream(rec):
     assert end["effective_steps"] == 12 and not end["truncated"]
     recov = next(e for e in rec.events if e["kind"] == "recovery")
     assert recov["strategy"] == "checkfree" and recov["stages"] == [1]
+    assert recov["path"] == "program"
     # wall-iter accounting in the windows matches the run
     ks = [e["k"] for e in rec.events if e["kind"] == "step_window"]
     assert sum(ks) == end["wall_iters"]
@@ -461,8 +462,8 @@ def test_trainer_emits_schema_valid_stream(rec):
 
 def test_failure_boundary_spans_nest_and_cover_the_boundary(rec):
     """A checkfree_plus failure: ``failures`` holds ``recovery``, which
-    holds the merge, the drain of its error and the moment reset, all
-    with the boundary's wall step; the boundary's own spans cover all but
+    holds the dispatch of the recovery program and the drain of its
+    error, all with the boundary's wall step; the boundary's own spans cover all but
     5% of the time from the drain before it to the next dispatch."""
     trainer = make_trainer(strategy="checkfree_plus", events={5: [1]})
     trainer.run(_batches())
@@ -473,10 +474,9 @@ def test_failure_boundary_spans_nest_and_cover_the_boundary(rec):
     assert {n: s["parent"] for n, s in at5.items()} == {
         "window_bookkeeping": None, "failures": None,
         "recovery": "failures", "recovery_merge": "recovery",
-        "recovery_error_drain": "recovery",
-        "recovery_moment_reset": "recovery", "window_prepare": None}
+        "recovery_error_drain": "recovery", "window_prepare": None}
     phases = sum(at5[n]["dur_us"] for n in (
-        "recovery_merge", "recovery_error_drain", "recovery_moment_reset"))
+        "recovery_merge", "recovery_error_drain"))
     assert phases <= at5["recovery"]["dur_us"] <= at5["failures"]["dur_us"]
     dispatch = [s for s in spans if s["name"] == "window_dispatch"]
     drain = [s for s in spans if s["name"] == "window_drain"]
