@@ -79,9 +79,14 @@ def ssd_chunked(xb: jnp.ndarray, a: jnp.ndarray, bmat: jnp.ndarray,
                     bc.astype(jnp.float32))                  # (b,nc,g,q,k)
     cbh = jnp.repeat(cb, r, axis=2)                          # heads (b,nc,h,q,k)
     csh = jnp.moveaxis(cs, 3, 2)                             # (b,nc,h,q)
-    decay = jnp.exp(csh[..., :, None] - csh[..., None, :])   # (b,nc,h,q,k)
     mask = jnp.tril(jnp.ones((chunk, chunk), bool))
-    att = jnp.where(mask[None, None, None], cbh * decay, 0.0)
+    # segment sums masked before the exp: above the diagonal they are
+    # positive and reach (chunk-1)*dt*|A|, whose exp overflows float32, and
+    # a mask after the exp would multiply that inf by a zero cotangent in
+    # the backward; exp(-inf) is exactly 0, with a zero derivative
+    seg = jnp.where(mask[None, None, None],
+                    csh[..., :, None] - csh[..., None, :], -jnp.inf)
+    att = cbh * jnp.exp(seg)                                 # (b,nc,h,q,k)
     y_intra = jnp.einsum("bchqk,bckhp->bcqhp", att,
                          xc.astype(jnp.float32))
 
@@ -207,26 +212,30 @@ def mamba_block(bp: Params, x: jnp.ndarray, cfg: ModelConfig,
     s = cfg.ssm
     b, t, d = x.shape
     d_in, nheads, conv_ch, _, n = block_dims(cfg)
-    h = L.rmsnorm(bp["norm"], x, cfg.rmsnorm_eps)
-    zxbcdt = h @ bp["w_in"]
-    z, xbc_raw, dt_raw = _split_proj(cfg, zxbcdt)
-    xbc = jax.nn.silu(causal_conv1d(xbc_raw, bp["conv_w"], bp["conv_b"]))
-    xs, bmat, cmat = _split_xbc(cfg, xbc)
-    xs = xs.reshape(b, t, nheads, s.head_dim)
-    bmat = bmat.reshape(b, t, s.ngroups, n)
-    cmat = cmat.reshape(b, t, s.ngroups, n)
-    dt = jax.nn.softplus(dt_raw.astype(jnp.float32) + bp["dt_bias"])  # (b,t,H)
-    amt = -jnp.exp(bp["a_log"])                                       # (H,)
-    a = dt * amt[None, None, :]
+    with jax.named_scope("ssm_in_proj"):
+        h = L.rmsnorm(bp["norm"], x, cfg.rmsnorm_eps)
+        zxbcdt = h @ bp["w_in"]
+        z, xbc_raw, dt_raw = _split_proj(cfg, zxbcdt)
+        dt = jax.nn.softplus(dt_raw.astype(jnp.float32)
+                             + bp["dt_bias"])                 # (b,t,H)
+        a = dt * -jnp.exp(bp["a_log"])[None, None, :]
+    with jax.named_scope("ssm_conv"):
+        xbc = jax.nn.silu(causal_conv1d(xbc_raw, bp["conv_w"],
+                                        bp["conv_b"]))
+        xs, bmat, cmat = _split_xbc(cfg, xbc)
+        xs = xs.reshape(b, t, nheads, s.head_dim)
+        bmat = bmat.reshape(b, t, s.ngroups, n)
+        cmat = cmat.reshape(b, t, s.ngroups, n)
     xb = xs * dt[..., None].astype(xs.dtype)
     chunk = min(s.chunk_size, t)
     while t % chunk != 0:
         chunk -= 1
     y, final_state = ssd_chunked(xb, a, bmat, cmat, chunk, init_state)
-    y = y + xs * bp["d_skip"][None, None, :, None].astype(xs.dtype)
-    y = y.reshape(b, t, d_in)
-    y = L.rmsnorm(bp["gate_norm"], y * jax.nn.silu(z), cfg.rmsnorm_eps)
-    out = y @ bp["w_out"]
+    with jax.named_scope("ssm_out"):
+        y = y + xs * bp["d_skip"][None, None, :, None].astype(xs.dtype)
+        y = y.reshape(b, t, d_in)
+        y = L.rmsnorm(bp["gate_norm"], y * jax.nn.silu(z), cfg.rmsnorm_eps)
+        out = y @ bp["w_out"]
     if return_state:
         # conv tail: last (K-1) pre-activation conv inputs
         k = s.conv_width
