@@ -6,9 +6,11 @@ other half the first two and last two transformer stages are swapped:
 S_K's) "for free" — no redundant compute, the swap is just a different
 composition order.
 
-With blocks stacked on axis 0, executing a swapped stage order is a static
-gather of layer indices — XLA compiles the normal and swapped programs once
-each (the TPU adaptation of SkipPipe's reordered execution, see DESIGN.md).
+With blocks stacked on axis 0, a swapped stage order is a static reordering
+of layer indices, which :func:`swap_permutation` gives.  The host backend
+(``core/trainer._swap_views``) applies it to the compute-dtype copy of the
+tower as static slices; the SPMD backend (``pipeline/spmd.py``) hops the
+swapped stages' slices between neighbouring devices with ``ppermute``.
 """
 from __future__ import annotations
 

@@ -9,7 +9,8 @@ and pricing wall-clock through its ``iteration_cost``/``failure_cost``.
 The loop itself is strategy-agnostic: it only consults the strategy's
 lifecycle hooks and capability flags, never its name.  CheckFree+'s
 out-of-order microbatches are realized by computing half the batch through a
-swapped stage order (a static layer-index gather — see core/swap.py).
+swapped stage order (the cast tower's layers reordered by static slices —
+see :func:`_swap_views` and core/swap.py).
 
 **Fused hot path.**  The failure schedule is deterministic and queryable
 ahead of time (``schedule.at(step)``), so between failure events the
@@ -56,6 +57,7 @@ from repro.core.state import History, TrainState  # noqa: F401  (re-export)
 from repro.core.swap import swap_permutation
 from repro.core.walltime import WallClockModel
 from repro.data.pipeline import WindowPrefetcher
+from repro.models import layers as L
 from repro.models.layers import cross_entropy
 from repro.models.model import Model
 from repro.optim.adam import adam_update, init_adam
@@ -64,12 +66,80 @@ from repro.recovery import FailureContext, RecoveryStrategy, make_strategy
 Params = Any
 
 
-@jax.named_scope("tower_swap")
-def _permute_tower(params: Params, tower_key: str, idx: jnp.ndarray) -> Params:
-    out = dict(params)
-    out[tower_key] = jax.tree.map(lambda a: jnp.take(a, idx, axis=0),
-                                  params[tower_key])
-    return out
+def _runs(perm: np.ndarray) -> Tuple[Tuple[int, int], ...]:
+    """``perm`` as its maximal runs of consecutive indices, ``(lo, hi)``."""
+    cuts = np.flatnonzero(np.diff(perm) != 1) + 1
+    return tuple((int(r[0]), int(r[-1]) + 1) for r in np.split(perm, cuts))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2))
+def _reorder(a: jnp.ndarray, runs, inverse) -> jnp.ndarray:
+    """``a``'s rows (axis 0) as the concatenation of its ``runs``; the
+    backward pass reorders the cotangent by ``inverse``, the runs of the
+    inverse permutation.  Static slices both ways: never a gather, whose
+    transpose is a scatter-add into zeros."""
+    with jax.named_scope("tower_swap"):
+        return jnp.concatenate([a[lo:hi] for lo, hi in runs], axis=0)
+
+
+def _reorder_fwd(a, runs, inverse):
+    return _reorder(a, runs, inverse), None
+
+
+def _reorder_bwd(runs, inverse, _, g):
+    return (_reorder(g, inverse, runs),)
+
+
+_reorder.defvjp(_reorder_fwd, _reorder_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1,))
+def _cast_shared(a: jnp.ndarray, dtype) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """``a`` cast to ``dtype`` once and handed to both half-batches; the
+    backward pass converts each half's cotangent to ``a``'s dtype and sums
+    them there (the float32 sum two separate casts would give, where
+    sharing one cast would sum in ``dtype``)."""
+    c = L.cast_tree(a, dtype)
+    return c, c
+
+
+def _cast_shared_fwd(a, dtype):
+    # the residual only carries ``a``'s dtype to the backward pass
+    return _cast_shared(a, dtype), jnp.zeros((0,), a.dtype)
+
+
+def _cast_shared_bwd(dtype, like, cts):
+    g1, g2 = cts
+    with jax.named_scope("param_cast"):
+        return (g1.astype(like.dtype) + g2.astype(like.dtype),)
+
+
+_cast_shared.defvjp(_cast_shared_fwd, _cast_shared_bwd)
+
+
+def _swap_views(params: Params, dtype, tower_key: str, runs, inverse,
+                ) -> Tuple[Params, Params]:
+    """CheckFree+'s parameter views for its two half-batches: ``params``
+    cast to the compute ``dtype`` once (the models' own entry cast is then
+    a no-op), and the same cast with the tower's layers in the swapped
+    order (``runs`` of the permutation, ``inverse`` those of its inverse;
+    :func:`_runs`), permuted in the compute dtype.  A leaf the cast leaves
+    as it is goes to both halves unchanged, so its gradient accumulates
+    exactly as it would without the views."""
+
+    def views(a, in_tower):
+        if jax.dtypes.issubdtype(a.dtype, jnp.floating) and a.dtype != dtype:
+            first, second = _cast_shared(a, dtype)
+        else:
+            first = second = a
+        if in_tower and len(runs) > 1:
+            second = _reorder(second, runs, inverse)
+        return first, second
+
+    flat, treedef = jax.tree_util.tree_flatten_with_path(params)
+    pairs = [views(a, path[0].key == tower_key) for path, a in flat]
+    return (treedef.unflatten([p[0] for p in pairs]),
+            treedef.unflatten([p[1] for p in pairs]))
 
 
 def _make_loss_fn(model: Model, part: StagePartition, use_swap: bool,
@@ -77,9 +147,11 @@ def _make_loss_fn(model: Model, part: StagePartition, use_swap: bool,
     """The (possibly swap-scheduled) loss closure shared by every step."""
     tower_key = part.tower_key
     if use_swap:
-        perm = jnp.asarray(swap_permutation(
+        perm = swap_permutation(
             part.num_layers, part.num_stages,
-            bounds=[part.stage_bounds(i) for i in range(part.num_stages)]))
+            bounds=[part.stage_bounds(i) for i in range(part.num_stages)])
+        runs, inverse = _runs(perm), _runs(np.argsort(perm))
+        dtype = jnp.dtype(model.cfg.dtype)
 
     def loss_fn(params, batch):
         if not use_swap:
@@ -88,8 +160,11 @@ def _make_loss_fn(model: Model, part: StagePartition, use_swap: bool,
         half = batch["tokens"].shape[0] // 2
         first = {k: v[:half] for k, v in batch.items()}
         second = {k: v[half:] for k, v in batch.items()}
-        l1, m1 = model.loss(params, first)
-        l2, m2 = model.loss(_permute_tower(params, tower_key, perm), second)
+        # the model's own entry cast is a no-op on these views
+        in_order, swapped = _swap_views(params, dtype, tower_key, runs,
+                                        inverse)
+        l1, m1 = model.loss(in_order, first)
+        l2, m2 = model.loss(swapped, second)
         # telemetry covers the WHOLE batch: average both halves' metrics
         # (the in-order half alone would silently drop half the ce/aux)
         metrics = {k: 0.5 * (m1[k] + m2[k]) for k in m1}
