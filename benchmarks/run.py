@@ -1,8 +1,8 @@
-"""Benchmark runner — one bench per paper table/figure + kernels + roofline.
+"""Benchmark runner — one bench per paper table/figure.
 
 Usage:
     PYTHONPATH=src python -m benchmarks.run              # full suite
-    PYTHONPATH=src python -m benchmarks.run --only fig2,kernels
+    PYTHONPATH=src python -m benchmarks.run --only fig2,fig3
     REPRO_BENCH_STEPS=120 PYTHONPATH=src python -m benchmarks.run  # faster
 
 Results land in ``benchmarks/results/*.json`` (+ cached strategy runs that
@@ -17,7 +17,6 @@ import traceback
 from repro.launch.compile_cache import configure_compile_cache
 
 BENCHES = {
-    "kernels": ("kernel microbenches vs oracle", "benchmarks.bench_kernels"),
     "fig2": ("reinit strategies", "benchmarks.bench_reinit"),
     "fig3": ("convergence under failures", "benchmarks.bench_convergence"),
     "table2": ("iteration/train wall-clock", "benchmarks.bench_throughput"),
@@ -28,7 +27,6 @@ BENCHES = {
     "sec44": ("recovery-error bound term", "benchmarks.bench_recovery_error"),
     "scenarios": ("simulated-cluster scenario sweep",
                   "benchmarks.bench_scenarios"),
-    "roofline": ("dry-run roofline report", "benchmarks.roofline"),
 }
 
 

@@ -1,8 +1,8 @@
 """Batched serving example: prefill + greedy decode across architectures.
 
 Runs the reduced variant of three assigned families (dense / MoE / SSM)
-through the same serving path the dry-run lowers at scale, and prints
-throughput.
+through ``Model.prefill`` and ``Model.decode_step``, the serving path of
+``repro.launch.serve``, and prints throughput.
 
     PYTHONPATH=src python examples/serve_batched.py
 """

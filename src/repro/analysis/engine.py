@@ -315,7 +315,7 @@ class ProjectContext:
             fn = index.canonical_callee(node.func)
             leaf = fn.split(".")[-1].lower() if fn is not None else ""
             # Mesh(devices, axis_names), jax.make_mesh(shape, names), and
-            # repo factories (make_mesh/make_pipeline_mesh/...) all
+            # repo factories (make_mesh/...) all
             # put the axis-name tuple in the second positional slot
             if fn in MESH_CTORS or "mesh" in leaf:
                 cands: List[ast.AST] = node.args[1:2]

@@ -101,7 +101,7 @@ class ModelConfig:
         return self.arch_type in ("dense", "moe", "ssm", "hybrid", "vlm")
 
     def param_count(self) -> int:
-        """Analytic parameter count (used for roofline MODEL_FLOPS)."""
+        """Analytic parameter count (sizes WallClockModel's transfers)."""
         d, hd = self.d_model, self.resolved_head_dim
         nq, nkv = self.num_heads, self.num_kv_heads
         emb = self.vocab_size * d
@@ -280,23 +280,3 @@ class ServeConfig:
     cache_len: int = 128
     swa_serving_window: int = 0   # >0: force ring-buffer SWA KV cache (long ctx)
     temperature: float = 0.0
-
-
-# ---------------------------------------------------------------------------
-# Input shape suite (assigned)
-# ---------------------------------------------------------------------------
-
-@dataclass(frozen=True)
-class InputShape:
-    name: str
-    seq_len: int
-    global_batch: int
-    kind: str  # "train" | "prefill" | "decode"
-
-
-INPUT_SHAPES = {
-    "train_4k": InputShape("train_4k", 4_096, 256, "train"),
-    "prefill_32k": InputShape("prefill_32k", 32_768, 32, "prefill"),
-    "decode_32k": InputShape("decode_32k", 32_768, 128, "decode"),
-    "long_500k": InputShape("long_500k", 524_288, 1, "decode"),
-}

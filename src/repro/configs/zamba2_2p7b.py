@@ -3,7 +3,7 @@ with shared attention blocks (32H MHA, d_ff=10240) interleaved every 9 SSM
 layers; vocab=32000.
 [arXiv:2411.15242]
 
-Simplification noted in DESIGN.md: zamba2 alternates two shared blocks and
+Simplification: zamba2 alternates two shared blocks and
 concatenates the original embedding at each shared block; we use one shared
 block with standard residual wiring (the staging/recovery mechanics are
 identical).

@@ -1,10 +1,5 @@
-"""Mesh construction for training, the dry-run and the production layouts.
-
-``make_production_mesh`` is a FUNCTION (not a module constant) so importing
-this module never touches jax device state — required because the dry-run
-forces ``xla_force_host_platform_device_count=512`` while tests/benches must
-see a single CPU device.  Every mesh goes through :func:`make_mesh`.
-"""
+"""Mesh construction for the SPMD training backend; every mesh goes
+through :func:`make_mesh`."""
 from __future__ import annotations
 
 import math
@@ -13,11 +8,6 @@ from typing import Optional, Sequence, Tuple
 
 import jax
 from jax.sharding import AxisType
-
-# TPU v5e hardware constants (per chip) — used by the roofline analysis
-PEAK_FLOPS_BF16 = 197e12          # FLOP/s
-HBM_BW = 819e9                    # B/s
-ICI_BW = 50e9                     # B/s per link
 
 
 def make_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...],
@@ -34,26 +24,6 @@ def make_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...],
     return jax.make_mesh(shape, axes,
                          axis_types=(AxisType.Auto,) * len(axes),
                          devices=devs[:need])
-
-
-def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh(shape, axes)
-
-
-def make_pipeline_mesh(*, num_stages: int, multi_pod: bool = False,
-                       ) -> jax.sharding.Mesh:
-    """Pipeline-parallel mesh: the 'model' axis becomes the stage axis.
-
-    data axis absorbs the remaining chips (paper setting: PP x DP).
-    """
-    chips = 512 if multi_pod else 256
-    assert chips % num_stages == 0, (chips, num_stages)
-    if multi_pod:
-        return make_mesh((2, chips // 2 // num_stages, num_stages),
-                         ("pod", "data", "stage"))
-    return make_mesh((chips // num_stages, num_stages), ("data", "stage"))
 
 
 def make_host_pipeline_mesh(num_stages: int) -> jax.sharding.Mesh:

@@ -3,9 +3,8 @@
     PYTHONPATH=src python -m repro.launch.serve --arch qwen3-4b --reduced \
         --batch 4 --prompt-len 32 --new-tokens 16
 
-Decode uses the same ``decode_step`` the dry-run lowers for decode_32k /
-long_500k (one token against a KV/SSM cache; sliding-window ring cache when
-the config or ``--window`` says so).
+Decode runs ``Model.decode_step``: one token against a KV/SSM cache, with a
+sliding-window ring cache when the config or ``--window`` says so.
 """
 from __future__ import annotations
 

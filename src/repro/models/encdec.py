@@ -113,8 +113,7 @@ def _dec_block(bp: Params, x: jnp.ndarray, positions: jnp.ndarray,
 
 
 def forward(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
-            frames: jnp.ndarray, *, remat: bool = False,
-            return_aux: bool = False):
+            frames: jnp.ndarray, *, return_aux: bool = False):
     """tokens: (B, S) decoder inputs; frames: (B, F, d) stub embeddings."""
     params = L.cast_tree(params, cfg.dtype)
     enc_out = encode(params, cfg, frames)
@@ -128,9 +127,6 @@ def forward(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
     def step(carry, bp):
         return _dec_block(bp, carry, positions, cfg, self_mask, enc_out), None
 
-    if remat:
-        step = jax.checkpoint(step,
-                              policy=jax.checkpoint_policies.nothing_saveable)
     x, _ = layer_scan(step, x, params["dec_blocks"])
     x = L.apply_norm(params["final_norm"], x, cfg)
     logits = L.unembed(params["embed"], x)  # tied

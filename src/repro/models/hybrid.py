@@ -4,7 +4,7 @@ A Mamba2 backbone with a single *shared* attention+MLP block applied every
 ``cfg.attn_every`` SSM layers (zamba2's shared transformer blocks).  The
 shared block has one parameter set reused at every application — which is
 exactly why its failure is handled by CheckFree+'s replication path rather
-than neighbour averaging (see DESIGN.md §Arch-applicability).
+than neighbour averaging.
 """
 from __future__ import annotations
 
@@ -57,7 +57,7 @@ def _reshape_seg(tree: Params, nseg: int, per: int) -> Params:
 
 
 def forward(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
-            *, remat: bool = False, return_aux: bool = False):
+            *, return_aux: bool = False):
     params = L.cast_tree(params, cfg.dtype)
     b, t = tokens.shape
     nseg, per = _nseg(cfg)
@@ -68,17 +68,12 @@ def forward(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
     mseg = _reshape_seg(params["mamba"], nseg, per)
 
     def seg_body(carry, seg_params):
-        from repro.launch.perf import constrain_activations
-
         def inner(c, bp):
-            return constrain_activations(c + S.mamba_block(bp, c, cfg)), None
+            return c + S.mamba_block(bp, c, cfg), None
         x2, _ = layer_scan(inner, carry, seg_params)
         x2 = _attn_apply(params["shared_attn"], x2, positions, cfg, mask)
-        return constrain_activations(x2), None
+        return x2, None
 
-    if remat:
-        from repro.launch.perf import remat_policy
-        seg_body = jax.checkpoint(seg_body, policy=remat_policy())
     x, _ = layer_scan(seg_body, x, mseg)
     x = L.rmsnorm(params["final_norm"], x, cfg.rmsnorm_eps)
     logits = (L.unembed(params["embed"], x) if cfg.tie_embeddings

@@ -51,30 +51,30 @@ class Model:
         raise ValueError(c.arch_type)
 
     # ---- forward -----------------------------------------------------
-    def apply(self, params: Params, batch: Batch, *, remat: bool = False,
-              ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    def apply(self, params: Params,
+              batch: Batch) -> Tuple[jnp.ndarray, jnp.ndarray]:
         """Full-sequence forward -> (logits, aux_loss)."""
         c = self.cfg
         toks = batch["tokens"]
         if c.arch_type in ("dense", "moe"):
-            return T.forward(params, c, toks, remat=remat, return_aux=True)
+            return T.forward(params, c, toks, return_aux=True)
         if c.arch_type == "ssm":
-            return S.forward(params, c, toks, remat=remat, return_aux=True)
+            return S.forward(params, c, toks, return_aux=True)
         if c.arch_type == "hybrid":
-            return H.forward(params, c, toks, remat=remat, return_aux=True)
+            return H.forward(params, c, toks, return_aux=True)
         if c.arch_type == "encdec":
-            return ED.forward(params, c, toks, batch["frames"], remat=remat,
+            return ED.forward(params, c, toks, batch["frames"],
                               return_aux=True)
         if c.arch_type == "vlm":
-            return V.forward(params, c, toks, batch["patches"], remat=remat,
+            return V.forward(params, c, toks, batch["patches"],
                              return_aux=True)
         raise ValueError(c.arch_type)
 
     # ---- loss --------------------------------------------------------
-    def loss(self, params: Params, batch: Batch, *, remat: bool = False,
-             ) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
+    def loss(self, params: Params,
+             batch: Batch) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
         c = self.cfg
-        logits, aux = self.apply(params, batch, remat=remat)
+        logits, aux = self.apply(params, batch)
         if c.arch_type == "vlm":
             logits = logits[:, batch["patches"].shape[1]:, :]
         mask = batch.get("loss_mask")
@@ -127,18 +127,6 @@ class Model:
         if c.arch_type == "vlm":
             return V.decode_step(params, c, cache, tokens, window=window)
         raise ValueError(c.arch_type)
-
-    # ---- batch specs (for dry-run lowering) ---------------------------
-    def extra_inputs(self, batch: int) -> Dict[str, jax.ShapeDtypeStruct]:
-        c = self.cfg
-        dt = jnp.dtype(c.dtype)
-        if c.arch_type == "encdec":
-            return {"frames": jax.ShapeDtypeStruct(
-                (batch, c.encoder_seq_len, c.d_model), dt)}
-        if c.arch_type == "vlm":
-            return {"patches": jax.ShapeDtypeStruct(
-                (batch, c.num_patches, V.D_PATCH), dt)}
-        return {}
 
     def param_count_actual(self, params: Params) -> int:
         return sum(x.size for x in jax.tree.leaves(params))

@@ -49,15 +49,11 @@ def _group_size(total_tokens: int, seq: int) -> int:
     """Pick a group size that divides the per-example token count.
 
     The one-hot dispatch/combine einsums cost O(T_g * C * d) per token with
-    C ~ T_g * k / E — QUADRATIC in the group size T_g.  Perf lever
-    ``REPRO_MOE_GROUP`` caps the group (GShard uses a few hundred); the
-    §Perf hillclimb measured 16x dispatch-FLOP reduction at 256 vs 4096 on
-    granite-moe x train_4k with identical expert compute.
+    C ~ T_g * k / E — QUADRATIC in the group size T_g — so the candidates
+    start at a cap of 4096 tokens (GShard uses a few hundred).
     """
-    import os
-    cap = int(os.environ.get("REPRO_MOE_GROUP", "4096"))
     for cand in (4096, 2048, 1024, 512, 256, 128, 64, 32, 16, 8, 4, 2, 1):
-        if cand <= min(seq, cap) and seq % cand == 0:
+        if cand <= seq and seq % cand == 0:
             return cand
     return 1
 

@@ -112,8 +112,8 @@ def ssd_chunked(xb: jnp.ndarray, a: jnp.ndarray, bmat: jnp.ndarray,
 
     s_chunk_t = jnp.moveaxis(s_chunk, 1, 0)                  # (nc,b,h,p,n)
     d_tot_t = jnp.moveaxis(d_tot, 1, 0)                      # (nc,b,h)
-    # NOTE: this scan runs over SEQUENCE CHUNKS, not layers — keep it a real
-    # lax.scan even when layer scans are unrolled for the dry-run analysis.
+    # NOTE: this scan runs over SEQUENCE CHUNKS, not layers, so it is a
+    # plain lax.scan and not a ``layer_scan``.
     final_state, prev_states = jax.lax.scan(step, init_state,
                                             (s_chunk_t, d_tot_t))
     prev_states = jnp.moveaxis(prev_states, 0, 1)            # (b,nc,h,p,n)
@@ -292,18 +292,13 @@ def init(key: jax.Array, cfg: ModelConfig) -> Params:
 
 
 def forward(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
-            *, remat: bool = False, return_aux: bool = False):
+            *, return_aux: bool = False):
     params = L.cast_tree(params, cfg.dtype)
     x = L.embed(params["embed"], tokens).astype(jnp.dtype(cfg.dtype))
 
     def body(carry, bp):
-        from repro.launch.perf import constrain_activations
-        return constrain_activations(carry + mamba_block(bp, carry, cfg)), \
-            None
+        return carry + mamba_block(bp, carry, cfg), None
 
-    if remat:
-        from repro.launch.perf import remat_policy
-        body = jax.checkpoint(body, policy=remat_policy())
     x, _ = layer_scan(body, x, params["blocks"])
     x = L.rmsnorm(params["final_norm"], x, cfg.rmsnorm_eps)
     if cfg.tie_embeddings:

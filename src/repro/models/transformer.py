@@ -113,7 +113,7 @@ def logits_from_hidden(params: Params, cfg: ModelConfig,
 
 
 def run_blocks(params: Params, cfg: ModelConfig, x: jnp.ndarray,
-               positions: jnp.ndarray, *, remat: bool = False,
+               positions: jnp.ndarray, *,
                offset: int = 0) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Scan the stacked decoder blocks over a full sequence (causal)."""
     s = x.shape[1]
@@ -121,15 +121,10 @@ def run_blocks(params: Params, cfg: ModelConfig, x: jnp.ndarray,
     swa_m = L.swa_mask(s, s, cfg.sliding_window, offset) \
         if cfg.sliding_window > 0 else full_mask
     block = _block_apply(cfg)
-    if remat:
-        from repro.launch.perf import remat_policy
-        block = jax.checkpoint(block, policy=remat_policy())
 
     def step(carry, xs):
         bp, flag = xs
-        x, aux = block(carry, bp, full_mask, swa_m, flag, positions)
-        from repro.launch.perf import constrain_activations
-        return constrain_activations(x), aux
+        return block(carry, bp, full_mask, swa_m, flag, positions)
 
     x, auxs = layer_scan(step, x, (params["blocks"], swa_flags(cfg)))
     return x, jnp.sum(auxs)
@@ -137,7 +132,7 @@ def run_blocks(params: Params, cfg: ModelConfig, x: jnp.ndarray,
 
 def forward(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
             *, inputs_embeds: Optional[jnp.ndarray] = None,
-            remat: bool = False, return_aux: bool = False):
+            return_aux: bool = False):
     """tokens: (B, S) -> logits (B, S, V).
 
     ``inputs_embeds``: optional (B, P, d) prefix embeddings (VLM stub) that are
@@ -153,7 +148,7 @@ def forward(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
     else:
         positions = jnp.broadcast_to(jnp.arange(s)[None], (b, s))
         x = embed_tokens(params, cfg, tokens, positions)
-    x, aux = run_blocks(params, cfg, x, positions, remat=remat)
+    x, aux = run_blocks(params, cfg, x, positions)
     logits = logits_from_hidden(params, cfg, x)
     if return_aux:
         return logits, aux

@@ -43,12 +43,11 @@ def project(params: Params, patches: jnp.ndarray, cfg: ModelConfig,
 
 
 def forward(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
-            patches: jnp.ndarray, *, remat: bool = False,
-            return_aux: bool = False):
+            patches: jnp.ndarray, *, return_aux: bool = False):
     """tokens: (B, S_text); patches: (B, P, d_patch).  Logits cover the full
     (P + S_text) sequence; the caller masks the image positions in the loss."""
     embeds = project(params, patches, cfg)
-    return T.forward(params, cfg, tokens, inputs_embeds=embeds, remat=remat,
+    return T.forward(params, cfg, tokens, inputs_embeds=embeds,
                      return_aux=return_aux)
 
 

@@ -1,7 +1,7 @@
 """Paper-faithful pipeline parallelism as shard_map + lax.ppermute — the
 SPMD **training backend** behind ``Trainer(backend="spmd")``.
 
-This is the TPU-native translation of the paper's setting (DESIGN.md §3):
+This is the TPU-native translation of the paper's setting (docs/pipeline.md):
 the mesh's ``"stage"`` axis *is* the pipeline; each device holds a
 contiguous slice of the stacked block tower (axis 0 sharded over "stage"),
 microbatch activations rotate stage-to-stage with ``lax.ppermute`` in a

@@ -222,8 +222,8 @@ def test_assigned_config_values(arch):
 
 
 def test_param_count_matches_actual():
-    """Analytic param_count must match the real init within 2% (it feeds
-    MODEL_FLOPS in the roofline)."""
+    """Analytic param_count must match the real init within 2% (it sizes
+    the wall-clock model's state transfers)."""
     from repro.models.model import build_model
     for a in ["gemma-2b", "granite-moe-3b-a800m", "mamba2-1.3b"]:
         cfg = reduced(get_config(a))

@@ -10,7 +10,6 @@ from hypothesis import given, settings, strategies as st  # noqa: E402
 from repro.core.failures import FailureSchedule
 from repro.core.swap import stage_permutations, swap_permutation
 from repro.kernels.stage_merge import stage_merge
-from repro.launch.shardings import batch_spec, cache_spec, param_spec
 
 SETTINGS = dict(max_examples=25, deadline=None)
 
@@ -90,105 +89,3 @@ def test_merge_convexity_property(n, w, seed):
     hi = np.maximum(np.asarray(x), np.asarray(y)) + 1e-5
     assert (got >= lo).all() and (got <= hi).all()
     assert got.shape == (n,)
-
-
-# ---------------------------------------------------------------------------
-# sharding rules: always valid, never shard indivisible dims
-# ---------------------------------------------------------------------------
-
-class _FakeMesh:
-    def __init__(self, data=16, model=16, pod=0):
-        self.axis_names = (("pod",) if pod else ()) + ("data", "model")
-        self.shape = dict(data=data, model=model)
-        if pod:
-            self.shape["pod"] = pod
-
-
-@settings(**SETTINGS)
-@given(dims=st.lists(st.integers(1, 4096), min_size=0, max_size=4),
-       model=st.sampled_from([4, 8, 16, 64]))
-def test_param_spec_divisibility(dims, model):
-    mesh = _FakeMesh(model=model)
-    spec = param_spec(tuple(dims), mesh)
-    for dim, s in zip(dims, spec):
-        if s == "model":
-            assert dim % model == 0 and dim >= model
-    # the stacked-layer axis of >=3D leaves is never sharded
-    if len(dims) >= 3:
-        assert spec[0] is None
-
-
-@settings(**SETTINGS)
-@given(batch=st.integers(1, 512), rest=st.lists(st.integers(1, 64),
-                                                max_size=2),
-       data=st.sampled_from([8, 16]), pod=st.sampled_from([0, 2]))
-def test_batch_spec_divisibility(batch, rest, data, pod):
-    mesh = _FakeMesh(data=data, pod=pod)
-    total = data * (pod or 1)
-    spec = batch_spec((batch, *rest), mesh)
-    if batch % total == 0 and batch >= total:
-        # PartitionSpec normalizes 1-tuples to bare axis names
-        want = ("pod", "data") if pod else "data"
-        assert spec[0] in (want, (want,) if isinstance(want, str) else want)
-    else:
-        assert spec[0] is None
-
-
-@settings(**SETTINGS)
-@given(shape=st.lists(st.integers(1, 2048), min_size=1, max_size=5),
-       model=st.sampled_from([8, 16]))
-def test_cache_spec_valid(shape, model):
-    mesh = _FakeMesh(model=model)
-    spec = cache_spec(tuple(shape), mesh)
-    for dim, s in zip(shape, spec):
-        if s == "model":
-            assert dim % model == 0
-        if s == ("data",):
-            assert dim % 16 == 0
-
-
-# ---------------------------------------------------------------------------
-# perf levers (hillclimb) keep the rules valid
-# ---------------------------------------------------------------------------
-
-@settings(**SETTINGS)
-@given(dims=st.lists(st.integers(1, 8192), min_size=1, max_size=4),
-       model=st.sampled_from([8, 16]), data=st.sampled_from([8, 16]))
-def test_param_spec_fsdp_divisibility(dims, model, data):
-    import os
-    mesh = _FakeMesh(data=data, model=model)
-    os.environ["REPRO_PARAM_SHARD"] = "fsdp"
-    try:
-        spec = param_spec(tuple(dims), mesh)
-    finally:
-        del os.environ["REPRO_PARAM_SHARD"]
-    for dim, s in zip(dims, spec):
-        if s == ("data", "model"):
-            assert dim % (data * model) == 0
-        elif s == "model":
-            assert dim % model == 0
-        elif s == "data":
-            assert dim % data == 0
-    if len(dims) >= 3:
-        assert spec[0] is None   # stacked-layer axis still never sharded
-
-
-def test_activation_constraint_noop_without_env():
-    import jax.numpy as jnp
-    from repro.launch.perf import activation_spec, constrain_activations
-    assert activation_spec() is None
-    x = jnp.ones((2, 4, 8))
-    assert constrain_activations(x) is x
-
-
-def test_activation_spec_modes():
-    import os
-    from jax.sharding import PartitionSpec as P
-    from repro.launch.perf import activation_spec
-    try:
-        os.environ["REPRO_ACT_SHARD"] = "feature"
-        assert activation_spec() == P(None, None, "model")
-        os.environ["REPRO_ACT_SHARD"] = "seq"
-        assert activation_spec() == P(None, "model", None)
-    finally:
-        del os.environ["REPRO_ACT_SHARD"]

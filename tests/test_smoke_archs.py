@@ -13,6 +13,7 @@ import pytest
 from repro.config import TrainConfig, OptimizerConfig, RecoveryConfig
 from repro.configs import ARCHS, reduced
 from repro.data import batch_for
+from repro.models import moe as MOE
 from repro.models.model import build_model
 from repro.optim import init_adam, adam_update
 from repro.config import OptimizerConfig
@@ -108,3 +109,28 @@ def test_decode_matches_forward_full_attention(arch):
                         lg_full[:, -1].astype(jnp.float32)).max())
     scale = float(jnp.abs(lg_full[:, -1]).max()) + 1e-6
     assert err / scale < tol, (cfg.name, err, scale)
+
+
+#: variables that once steered how the layers were traced
+_STRAY_ENV = {"REPRO_UNROLL_SCAN": "1", "REPRO_ACT_SHARD": "seq",
+              "REPRO_REMAT": "dots", "REPRO_PARAM_SHARD": "fsdp",
+              "REPRO_MOE_GROUP": "2"}
+
+
+def test_loss_ignores_environment(arch, monkeypatch):
+    """The traced loss is a function of the configuration alone."""
+    cfg, model, params = arch
+    batch = make_batch(cfg)
+    for name in _STRAY_ENV:
+        monkeypatch.delenv(name, raising=False)
+    clean = str(jax.make_jaxpr(model.loss)(params, batch))
+    for name, value in _STRAY_ENV.items():
+        monkeypatch.setenv(name, value)
+    assert str(jax.make_jaxpr(model.loss)(params, batch)) == clean, cfg.name
+
+
+@pytest.mark.parametrize("seq,group", [(512, 512), (1024, 1024),
+                                       (8192, 4096), (100, 4), (6, 2)])
+def test_moe_group_size(seq, group):
+    """The largest power of two up to 4096 that divides the sequence."""
+    assert MOE._group_size(4 * seq, seq) == group

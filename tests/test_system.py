@@ -1,9 +1,8 @@
 """End-to-end system behaviour tests: trainer x recovery strategies,
-checkpoint rollback, failure bookkeeping, wall-clock model, data pipeline,
-and the dry-run's HLO collective parser."""
+checkpoint rollback, failure bookkeeping, wall-clock model and data
+pipeline."""
 import os
 
-import jax  # noqa: F401  — lock device count before importing dryrun below
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -147,29 +146,3 @@ def test_batch_for_adds_modalities():
     b = batch_for(vlm_cfg, raw)
     assert b["patches"].shape[:2] == (2, 4)
     assert b["tokens"].shape == (2, 16)
-
-
-# ---------------------------------------------------------------------------
-# dry-run HLO collective parser (pure function — no 512-device init here)
-# ---------------------------------------------------------------------------
-
-def test_collective_bytes_parser():
-    from repro.launch.dryrun import collective_bytes
-    hlo = """
-  %ag = bf16[8,128]{1,0} all-gather(%x), replica_groups={...}
-  %ar.1 = f32[1024]{0} all-reduce(%y), to_apply=%add
-  ROOT %rs = (f32[256]{0}, f32[256]{0}) reduce-scatter(%a, %b)
-  %cp = u8[16]{0} collective-permute(%z)
-  %not_a_coll = f32[99]{0} add(%p, %q)
-"""
-    got = collective_bytes(hlo)
-    assert got["all-gather"] == 8 * 128 * 2
-    assert got["all-reduce"] == 1024 * 4
-    assert got["reduce-scatter"] == 2 * 256 * 4
-    assert got["collective-permute"] == 16
-    assert "add" not in got
-
-
-def test_collective_bytes_empty():
-    from repro.launch.dryrun import collective_bytes
-    assert collective_bytes("%x = f32[2] add(%a, %b)") == {}
